@@ -60,6 +60,7 @@ def main() -> None:
     from benchmarks import isx, kmer, lm_step, meraculous, micro_hashmap, \
         micro_queue
     from benchmarks.util import HEADER
+    from repro.launch.compile_cache import enable_compile_cache
     mods = {
         "micro_hashmap": micro_hashmap,
         "micro_queue": micro_queue,
@@ -97,7 +98,9 @@ def main() -> None:
         del args[i:i + 2]
     args = [a for a in args if a not in ("--smoke", "--fused", "--faults", "--async")]
     only = args[0] if args else None
+    enable_compile_cache()
     print(HEADER)
+    failed = []
     for name, mod in mods.items():
         if only and name != only:
             continue
@@ -128,6 +131,9 @@ def main() -> None:
                 mod.run(**kw)
         except Exception as e:  # keep the harness going; report the row
             print(f"{name},ERROR,,,,,,,,,,,,,{type(e).__name__}: {e}")
+            failed.append(name)
+    if failed:
+        sys.exit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

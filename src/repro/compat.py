@@ -1,64 +1,23 @@
-"""Version compatibility shims over the installed JAX.
+"""The one JAX default this repo overrides.
 
-The repo targets the modern JAX surface (``jax.sharding.AxisType``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.shard_map``,
-``jax.lax.axis_size``); older installs expose the same functionality
-under different names or without the newer keywords.  Everything that
-touches one of those entry points goes through this module so the rest
-of the codebase is written once against the modern API.
+``jax.make_mesh`` makes every axis ``Explicit`` by default in the
+installed JAX (0.9), which types sharding into every array; the library
+and its tests are written for ``Auto`` axes, where the partitioner
+places arrays and ``jax.shard_map`` regions name their own specs.
 """
 
 from __future__ import annotations
 
-import math
 from typing import Sequence
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType  # noqa: F401
-    _HAS_AXIS_TYPES = True
-except ImportError:  # pragma: no cover - depends on installed jax
-    class AxisType:  # type: ignore[no-redef]
-        """Placeholder enum: old JAX has implicit (auto) axes only."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    _HAS_AXIS_TYPES = False
-
-
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-    shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, mesh, in_specs, out_specs, **kw):
-        # old shard_map spells check_vma as check_rep, and its replication
-        # checker predates several collective rep rules used here;
-        # correctness is covered by the out_specs.
-        kw.pop("check_vma", None)
-        kw.setdefault("check_rep", False)
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
-              axis_types=None):
-    """``jax.make_mesh`` that tolerates old JAX without ``axis_types``."""
-    if _HAS_AXIS_TYPES:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def axis_size(axis) -> int:
-    """Static size of a named mesh axis (product for a tuple of names)."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis))
-    from jax.core import axis_frame  # old jax: returns the static size
-    if isinstance(axis, tuple):
-        return math.prod(int(axis_frame(a)) for a in axis)
-    return int(axis_frame(axis))
+              axis_types=None, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes unless told otherwise."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types,
+                         devices=devices)

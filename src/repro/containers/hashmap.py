@@ -66,8 +66,8 @@ class HashMapSpec:
 
 
 class HashMapState(NamedTuple):
-    tkeys: jax.Array    # (nb_local, B, Lk) u32
-    tvals: jax.Array    # (nb_local, B, Lv) u32
+    tkeys: jax.Array    # (nb_local, Lk, B) u32, lane-major per block
+    tvals: jax.Array    # (nb_local, Lv, B) u32
     status: jax.Array   # (nb_local, B) u32
 
 
@@ -82,8 +82,8 @@ def hashmap_create(backend: Backend, capacity: int, key_spec, val_spec,
     nb_local = nb_global // nprocs
     spec = HashMapSpec(nb_global, nb_local, block_size, kp, vp, impl)
     state = HashMapState(
-        jnp.zeros((nb_local, block_size, kp.lanes), _U32),
-        jnp.zeros((nb_local, block_size, vp.lanes), _U32),
+        jnp.zeros((nb_local, kp.lanes, block_size), _U32),
+        jnp.zeros((nb_local, vp.lanes, block_size), _U32),
         jnp.zeros((nb_local, block_size), _U32))
     return spec, state
 
@@ -501,8 +501,10 @@ def local_entries(spec: HashMapSpec, state: HashMapState):
     from repro.kernels.ref import READY, bucket_state
     nb, b = state.status.shape
     occ = (bucket_state(state.status) == READY).reshape(-1)
-    keys = spec.key_packer.unpack(state.tkeys.reshape(nb * b, -1))
-    vals = spec.val_packer.unpack(state.tvals.reshape(nb * b, -1))
+    keys = spec.key_packer.unpack(
+        state.tkeys.transpose(0, 2, 1).reshape(nb * b, -1))
+    vals = spec.val_packer.unpack(
+        state.tvals.transpose(0, 2, 1).reshape(nb * b, -1))
     return keys, vals, occ
 
 
@@ -522,7 +524,7 @@ def export_state(spec: HashMapSpec, state: HashMapState) -> dict:
 def restore_state(spec: HashMapSpec, exported: dict) -> HashMapState:
     """Rebuild a HashMapState shard from :func:`export_state` output."""
     tk = jnp.asarray(exported["tkeys"], _U32)
-    want = (spec.nblocks_local, spec.block_size, spec.key_packer.lanes)
+    want = (spec.nblocks_local, spec.key_packer.lanes, spec.block_size)
     if tk.shape != want:
         raise ValueError(
             f"hashmap.restore_state: tkeys shape {tk.shape} does not "

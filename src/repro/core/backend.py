@@ -159,8 +159,7 @@ class SpmdBackend(Backend):
         self.axis = axis
         # axis size must be static; read it from the ambient mesh if not given.
         if axis_size is None:
-            from repro.compat import axis_size as _axis_size
-            axis_size = _axis_size(axis)
+            axis_size = jax.lax.axis_size(axis)
         self._nprocs = int(axis_size)
 
     def nprocs(self) -> int:

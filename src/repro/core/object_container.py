@@ -111,13 +111,33 @@ def scatter_rows(flat: jax.Array, base: jax.Array, rows: jax.Array,
     are dropped — one rectangular call packs right-padded rows of mixed
     flow widths bit-identically to per-flow calls on disjoint slots.
     """
+    # word indices are built lane-major, (W, N): with the N rows on the
+    # minor axis no index or update array is padded out to the vector
+    # width on TPU, as an (N, W) one with a few lanes would be
     w = rows.shape[1]
-    lane = jnp.arange(w, dtype=base.dtype)[None, :]
-    idx = base[:, None] + lane
+    lane = jnp.arange(w, dtype=base.dtype)[:, None]
+    idx = base[None, :] + lane
     if widths is not None:
-        idx = jnp.where(lane < widths[:, None].astype(base.dtype), idx,
+        idx = jnp.where(lane < widths[None, :].astype(base.dtype), idx,
                         flat.shape[0])
-    return flat.at[idx].set(rows.astype(_U32), mode="drop")
+    return flat.at[idx.reshape(-1)].set(rows.astype(_U32).T.reshape(-1),
+                                        mode="drop")
+
+
+def rows_from_words(words: jax.Array, roww: int) -> jax.Array:
+    """View a run of ``roww``-word rows as an (N, roww) u32 matrix.
+
+    The inverse of :func:`scatter_rows` over dense slots: ``words`` (any
+    shape, flattened row-major) holds whole rows back to back.  The rows
+    are gathered lane-major, (roww, N), and transposed, so on TPU they
+    need not take a row-major (N, roww) layout, which a reshape would
+    force and which pads a few lanes out to the 128-lane vector.
+    """
+    flat = words.reshape(-1)
+    n = flat.shape[0] // roww
+    idx = (jnp.arange(n, dtype=jnp.int32)[None, :] * roww
+           + jnp.arange(roww, dtype=jnp.int32)[:, None])
+    return flat[idx].T
 
 
 class Packer(abc.ABC):

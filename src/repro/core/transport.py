@@ -34,7 +34,7 @@ Two transports ship:
 
 Hierarchical wire format: each hop's row is the flow's dense row
 (``L_f`` payload lanes + the meta lane) plus ONE hop lane packing
-``rank << 20 | o`` where ``o`` is the item's within-(dest, flow)-bucket
+``rank << s | o`` where ``o`` is the item's within-(dest, flow)-bucket
 rank from the ONE dense binning pass.  On the source->relay hop the
 rank field is the final destination (the relay re-bins on it); the
 relay rewrites it to the source rank (recovered positionally from the
@@ -42,8 +42,10 @@ stage-1 arrival block) so the owner can scatter each arrival straight
 into the dense layout slot ``src * R*C_f + o`` — which is what makes
 hierarchical results bit-identical to :class:`DenseTransport` whenever
 the stage capacities admit every dense-admitted item (the default
-sizing guarantees it).  The packing bounds the transport to
-``P <= 4096`` ranks and effective capacities below ``2**20``.
+sizing guarantees it).  The rank field takes the ``bits(P - 1)`` bits
+the axis needs (:func:`hop_shift`), leaving ``s = 32 - bits(P - 1)``
+bits for ``o``: more than any bucket rank of a wire whose ``P * R*C_f``
+word slots fit the i32 indices.
 
 Cost attribution (DESIGN.md §1.7): the hop that touches the requester
 is charged under the flow's own ``op_name`` (request ``bytes_out``,
@@ -67,7 +69,7 @@ import jax.numpy as jnp
 
 from repro.core import costs
 from repro.core.backend import Backend
-from repro.core.object_container import ragged_offsets
+from repro.core.object_container import ragged_offsets, rows_from_words
 from repro.kernels import ops as kops
 
 _U32 = jnp.uint32
@@ -75,10 +77,12 @@ _I32 = jnp.int32
 
 _VALID_BIT = jnp.uint32(1 << 31)
 
-#: hop lane packing: bits [20, 32) = rank, bits [0, 20) = within-bucket rank
-_HOP_SHIFT = 20
-_HOP_MASK = (1 << _HOP_SHIFT) - 1
-_MAX_RANKS = 1 << (32 - _HOP_SHIFT)
+
+def hop_shift(nprocs: int) -> int:
+    """Hop lane packing on a ``nprocs``-rank axis: bits ``[shift, 32)``
+    hold a rank, bits ``[0, shift)`` a within-bucket rank — the rank
+    field takes only the bits the axis needs."""
+    return 32 - max(1, (nprocs - 1).bit_length())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -256,14 +260,13 @@ class DenseTransport(Transport):
         for fi, s in enumerate(specs):
             # rounds concatenate per source: owner row s*(R*C_f) + o holds
             # the rank-o arrival from rank s, exactly the single-round
-            # layout at capacity R*C_f; the flow's word segment reshapes
-            # straight to its own (rows, L_f+1) width
+            # layout at capacity R*C_f; the flow's word segment reads
+            # back as its own (rows, L_f+1) rows
             parts = [recvs[r][:, woffs_by_round[r][fi]:
                               woffs_by_round[r][fi] + s.capacity * s.roww]
-                     .reshape(nprocs, s.capacity, s.roww)
                      for r in range(s.rounds)]
-            segments.append(jnp.stack(parts, axis=1)
-                            .reshape(nprocs * s.cap_e, s.roww))
+            segments.append(rows_from_words(jnp.stack(parts, axis=1),
+                                            s.roww))
 
         # cost attribution: per-flow wire segments are ragged, so each
         # flow's bytes are EXACT — its own capacity x its own row width,
@@ -318,7 +321,7 @@ class DenseTransport(Transport):
             cap = specs[fi].cap_e
             rl = rls[fi]
             seg = back2[:, seg_off[fi]:seg_off[fi] + cap * rl]
-            outs[fi] = seg.reshape(nprocs * cap, rl)
+            outs[fi] = rows_from_words(seg, rl)
             fb = nprocs * cap * rl * 4
             costs.record(specs[fi].op_name,
                          costs.Cost(bytes_moved=fb, bytes_in=fb))
@@ -381,6 +384,7 @@ class _HierPre:
     destcol: jax.Array
     hop1: jax.Array
     rows1: jax.Array   # (N, max w1) right-padded stage-1 rows, hop lane last
+    shift: int         # hop lane packing, :func:`hop_shift`
 
 
 @dataclasses.dataclass
@@ -468,15 +472,10 @@ class HierarchicalTransport(Transport):
         specs = args.specs
         nprocs = backend.nprocs()
         pr, pc = self._factor(nprocs)
-        if nprocs > _MAX_RANKS:
-            raise ValueError(
-                f"HierarchicalTransport hop lane packs rank<<{_HOP_SHIFT}: "
-                f"{nprocs} ranks exceeds the {_MAX_RANKS} bound")
-        for s in specs:
-            if s.cap_e > _HOP_MASK:
-                raise ValueError(
-                    f"flow '{s.op_name}': effective capacity {s.cap_e} "
-                    f"exceeds the hop lane's {_HOP_MASK} bound")
+        # every bucket rank fits the hop lane's low bits: one that needed
+        # more than ``shift`` bits would put the wire's P * cap_e word
+        # slots past the i32 indices first (P > 2**(31 - shift))
+        shift = hop_shift(nprocs)
         row_groups = tuple(tuple(i * pc + j for j in range(pc))
                            for i in range(pr))
         col_groups = tuple(tuple(i * pc + j for i in range(pr))
@@ -493,8 +492,8 @@ class HierarchicalTransport(Transport):
 
         destcol = (args.dest % pc).astype(_I32)
         # hop lane, source->relay: final dest rank | dense bucket rank o
-        hop1 = ((args.dest.astype(_U32) << _HOP_SHIFT)
-                | (args.offsets.astype(_U32) & _U32(_HOP_MASK)))
+        hop1 = ((args.dest.astype(_U32) << shift)
+                | (args.offsets.astype(_U32) & _U32((1 << shift) - 1)))
         # stage-1 rows (body + hop lane), launch-invariant: every round
         # packs a window of the same matrix through the fused kernel
         row0, w1max = 0, max(w1)
@@ -508,7 +507,7 @@ class HierarchicalTransport(Transport):
         return _HierPre(args, pr, pc, row_groups, col_groups, myrow,
                         caps_arr, rounds_arr, w1, w1_arr, c1, c2,
                         jnp.asarray(c1, _I32), jnp.asarray(c2, _I32),
-                        nrounds, destcol, hop1, rows1)
+                        nrounds, destcol, hop1, rows1, shift)
 
     def _stage1(self, backend, pre, r):
         """Round r's source->relay hop: bin by dest column, row a2a."""
@@ -570,18 +569,18 @@ class HierarchicalTransport(Transport):
         rel_bins, rel_flow, rel_valid, rel_rows = [], [], [], []
         for fi in live:
             s = specs[fi]
-            seg = recv1[:, woff1_map[fi]:
-                        woff1_map[fi] + c1[fi] * w1[fi]] \
-                .reshape(pc * c1[fi], w1[fi])
+            seg = rows_from_words(
+                recv1[:, woff1_map[fi]:woff1_map[fi] + c1[fi] * w1[fi]],
+                w1[fi])
             meta = seg[:, s.roww - 1]
             hop = seg[:, s.roww]
             rv = (meta & _VALID_BIT) != 0
-            dst = (hop >> _HOP_SHIFT).astype(_I32)
-            o = (hop & _U32(_HOP_MASK))
+            dst = (hop >> pre.shift).astype(_I32)
+            o = (hop & _U32((1 << pre.shift) - 1))
             # stage-1 arrival block index IS the source's column
             src_col = jnp.arange(pc * c1[fi], dtype=_I32) // c1[fi]
             src = (pre.myrow * pc + src_col).astype(_U32)
-            hop2 = (src << _HOP_SHIFT) | o
+            hop2 = (src << pre.shift) | o
             rel_rows.append(jnp.concatenate(
                 [seg[:, :s.roww], hop2[:, None]], axis=1))
             rel_bins.append(jnp.where(rv, dst // pc, 0))
@@ -625,14 +624,14 @@ class HierarchicalTransport(Transport):
         scatters = {}
         for fi in live:
             s = specs[fi]
-            seg2 = recv2[:, woff2_map[fi]:
-                         woff2_map[fi] + c2[fi] * w1[fi]] \
-                .reshape(pr * c2[fi], w1[fi])
+            seg2 = rows_from_words(
+                recv2[:, woff2_map[fi]:woff2_map[fi] + c2[fi] * w1[fi]],
+                w1[fi])
             meta2 = seg2[:, s.roww - 1]
             hop2v = seg2[:, s.roww]
             v2 = (meta2 & _VALID_BIT) != 0
-            src2 = (hop2v >> _HOP_SHIFT).astype(_I32)
-            o2 = (hop2v & _U32(_HOP_MASK)).astype(_I32)
+            src2 = (hop2v >> pre.shift).astype(_I32)
+            o2 = (hop2v & _U32((1 << pre.shift) - 1)).astype(_I32)
             dslot = jnp.where(v2, src2 * s.cap_e + o2,
                               nprocs * s.cap_e).astype(_I32)
             scatters[fi] = (dslot, seg2[:, :s.roww])
@@ -647,7 +646,7 @@ class HierarchicalTransport(Transport):
         pr, pc, w1, c1, c2 = pre.pr, pre.pc, pre.w1, pre.c1, pre.c2
         nprocs = backend.nprocs()
 
-        seg_out = [jnp.zeros((nprocs * s.cap_e, s.roww), _U32)
+        seg_out = [jnp.zeros((nprocs * s.cap_e * s.roww,), _U32)
                    for s in specs]
         extra = jnp.zeros((nflows,), _I32)
         for out in rounds:
@@ -655,11 +654,11 @@ class HierarchicalTransport(Transport):
                 # dense-slot owner scatter through the in-kernel placer:
                 # word slot = row slot * row width, sentinel rows (dslot
                 # == P * cap_e) land exactly at the buffer size and drop
-                s = specs[fi]
                 seg_out[fi] = kops.place_rows(
-                    seg_out[fi].reshape(-1), dslot * s.roww, rows,
-                    impl=args.impl).reshape(nprocs * s.cap_e, s.roww)
+                    seg_out[fi], dslot * specs[fi].roww, rows,
+                    impl=args.impl)
             extra = extra + out.extra
+        seg_out = [rows_from_words(w, s.roww) for w, s in zip(seg_out, specs)]
 
         # cost attribution: the requester-side hop under the flow's own
         # op (retry launches under "<op>.retry"); ALL relay->owner hop
@@ -748,8 +747,8 @@ class HierarchicalTransport(Transport):
             parts = []
             for fi in rf:
                 rl = rls[fi]
-                rep2 = back2[:, woff:woff + c2[fi] * rl] \
-                    .reshape(pr * c2[fi], rl)
+                rep2 = rows_from_words(back2[:, woff:woff + c2[fi] * rl],
+                                       rl)
                 woff += c2[fi] * rl
                 r2 = rnd.rel[fi]                       # (pc*c1,) sentinel
                 in_r = r2 < pr * c2[fi]
@@ -765,25 +764,24 @@ class HierarchicalTransport(Transport):
             .reshape(pc, wtot1)
 
         # ---- source: land replies in the dense send-slot layout ----
-        outs = {fi: jnp.zeros((nprocs * specs[fi].cap_e, rls[fi]), _U32)
+        outs = {fi: jnp.zeros((nprocs * specs[fi].cap_e * rls[fi],), _U32)
                 for fi in staged}
         woff = 0
         for rnd, rf in zip(ctx.rounds, layout):
             for fi in rf:
-                s = specs[fi]
                 rl = rls[fi]
-                rep1 = back1[:, woff:woff + c1[fi] * rl] \
-                    .reshape(pc * c1[fi], rl)
+                rep1 = rows_from_words(back1[:, woff:woff + c1[fi] * rl],
+                                       rl)
                 woff += c1[fi] * rl
                 r1, dslot = rnd.src[fi]
                 in_r = r1 < pc * c1[fi]
                 rows = jnp.where(
                     in_r[:, None],
                     rep1[jnp.minimum(r1, pc * c1[fi] - 1)], 0)
-                outs[fi] = kops.place_rows(
-                    outs[fi].reshape(-1), dslot * rl, rows,
-                    impl=ctx.impl).reshape(nprocs * s.cap_e, rl)
+                outs[fi] = kops.place_rows(outs[fi], dslot * rl, rows,
+                                           impl=ctx.impl)
 
+        outs = {fi: rows_from_words(w, rls[fi]) for fi, w in outs.items()}
         for fi in sorted(staged):
             s = specs[fi]
             b1 = pc * c1[fi] * rls[fi] * 4 * s.rounds

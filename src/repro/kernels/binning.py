@@ -10,16 +10,20 @@ partial histograms accumulated across grid steps in the output block
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import interpret_mode as _interpret
+from repro.kernels import pallas_call
 
 _I32 = jnp.int32
 _F32 = jnp.float32
 _U32 = jnp.uint32
+_BF16 = jnp.bfloat16
+_LANES = 128
 
 
 def _hist_kernel(bins_ref, valid_ref, out_ref, *, nbins: int):
@@ -54,13 +58,27 @@ def _offsets_kernel(bins_ref, valid_ref, counts_ref, off_ref, *, nbins: int):
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
     bins = bins_ref[...].astype(_I32)                      # (TM,)
-    valid = valid_ref[...]                                 # (TM,)
+    valid = valid_ref[...].astype(_I32)                    # (TM,)
     tm = bins.shape[0]
     onehot = ((bins[:, None] ==
                jax.lax.broadcasted_iota(_I32, (tm, nbins), 1))
-              & valid[:, None]).astype(_I32)               # (TM, NB)
-    # stable within-tile rank: exclusive cumsum down each bin column
-    within = jnp.cumsum(onehot, axis=0) - onehot
+              .astype(_I32) * valid[:, None])              # (TM, NB)
+    # stable within-tile rank: exclusive cumsum down each bin column.
+    # Mosaic has no cumsum, so each sub-block of SB rows takes its
+    # prefix as a strictly-lower-triangular ones matmul on the MXU (0/1
+    # operands are exact in bf16, counts exact in the f32 accumulator),
+    # plus the running column count of the sub-blocks before it.
+    sb = math.gcd(tm, 128)
+    tri = (jax.lax.broadcasted_iota(_I32, (sb, sb), 0)
+           > jax.lax.broadcasted_iota(_I32, (sb, sb), 1)).astype(_BF16)
+    run = jnp.zeros((1, nbins), _I32)
+    parts = []
+    for s in range(tm // sb):
+        blk = onehot[s * sb:(s + 1) * sb]
+        parts.append(jnp.dot(tri, blk.astype(_BF16),
+                             preferred_element_type=_F32).astype(_I32) + run)
+        run = run + blk.sum(axis=0, keepdims=True)
+    within = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
     base = counts_ref[...]                                 # tiles [0, i)
     off_ref[...] = ((within + base[None, :]) * onehot).sum(axis=1)
     # fold this tile's histogram into the running counts on the MXU
@@ -91,7 +109,7 @@ def bin_offsets(bins: jax.Array, nbins: int, valid: jax.Array | None = None,
         valid = jnp.pad(valid, (0, pad))
     mp = bins.shape[0]
     kern = functools.partial(_offsets_kernel, nbins=nbins)
-    counts, offs = pl.pallas_call(
+    counts, offs = pallas_call(
         kern,
         grid=(mp // tile,),
         in_specs=[pl.BlockSpec((tile,), lambda i: (i,)),
@@ -100,36 +118,34 @@ def bin_offsets(bins: jax.Array, nbins: int, valid: jax.Array | None = None,
                    pl.BlockSpec((tile,), lambda i: (i,))],
         out_shape=(jax.ShapeDtypeStruct((nbins,), _I32),
                    jax.ShapeDtypeStruct((mp,), _I32)),
-        interpret=_interpret(),
     )(bins.astype(_I32), valid)
     return counts, offs[:m]
 
 
-def _ragged_slots_kernel(bins_ref, flow_ref, off_ref, valid_ref,
-                         woff_ref, roww_ref, caps_ref, rounds_ref,
+def _ragged_slots_kernel(woff_ref, roww_ref, caps_ref, rounds_ref,
+                         bins_ref, flow_ref, off_ref, valid_ref,
                          slot_ref, *, nflows: int, rnd: int, wtot: int,
                          sentinel: int):
     """Per-item ragged word slot off the ONE binning pass.
 
-    Flow tables (word offset, row words, capacity, rounds) are gathered
-    by flow id via a one-hot contraction (nflows is tiny), then the
+    Items are a lane-dense (rows, 128) tile; the flow tables (word
+    offset, row words, capacity, rounds) sit in SMEM and each item picks
+    its flow's entries by a select per flow (nflows is tiny).  Then the
     retry-round window ``[rnd*C_f, (rnd+1)*C_f)`` masks which items ride
     this launch — the §1.6 mask and the §1.5 ragged layout fused into
     one elementwise pass, with no second binning.
     """
-    bins = bins_ref[...].astype(_I32)
-    flow = flow_ref[...].astype(_I32)
-    off = off_ref[...].astype(_I32)
-    valid = valid_ref[...]
-    tm = bins.shape[0]
-    oh = (flow[:, None] ==
-          jax.lax.broadcasted_iota(_I32, (tm, nflows), 1)).astype(_I32)
-
-    def sel(tbl_ref):
-        return (oh * tbl_ref[...][None, :]).sum(axis=1)
-
-    woff_i, roww_i = sel(woff_ref), sel(roww_ref)
-    cap_i, rnds_i = sel(caps_ref), sel(rounds_ref)
+    bins = bins_ref[...]
+    flow = flow_ref[...]
+    off = off_ref[...]
+    valid = valid_ref[...] != 0
+    woff_i = roww_i = cap_i = rnds_i = jnp.zeros_like(bins)
+    for f in range(nflows):
+        at = flow == f
+        woff_i = jnp.where(at, woff_ref[f], woff_i)
+        roww_i = jnp.where(at, roww_ref[f], roww_i)
+        cap_i = jnp.where(at, caps_ref[f], cap_i)
+        rnds_i = jnp.where(at, rounds_ref[f], rnds_i)
     off_r = off - rnd * cap_i
     in_r = valid & (rnds_i > rnd) & (off_r >= 0) & (off_r < cap_i)
     slot_ref[...] = jnp.where(in_r, bins * wtot + woff_i + off_r * roww_i,
@@ -153,33 +169,25 @@ def ragged_slots(bins: jax.Array, flow: jax.Array, offsets: jax.Array,
     m = bins.shape[0]
     nflows = word_off.shape[0]
     pad = (-m) % tile
-    if pad:
-        bins = jnp.pad(bins, (0, pad))
-        flow = jnp.pad(flow, (0, pad))
-        offsets = jnp.pad(offsets, (0, pad))
-        valid = jnp.pad(valid, (0, pad))
-    mp = bins.shape[0]
+    rows = (m + pad) // _LANES
+
+    def dense(x):
+        return jnp.pad(x.astype(_I32), (0, pad)).reshape(rows, _LANES)
+
     kern = functools.partial(_ragged_slots_kernel, nflows=nflows,
                              rnd=rnd, wtot=wtot, sentinel=sentinel)
-    full = lambda i: (0,)
-    slots = pl.pallas_call(
+    table = pl.BlockSpec(memory_space=pltpu.SMEM)
+    items = pl.BlockSpec((tile // _LANES, _LANES), lambda i: (i, 0))
+    slots = pallas_call(
         kern,
-        grid=(mp // tile,),
-        in_specs=[pl.BlockSpec((tile,), lambda i: (i,)),
-                  pl.BlockSpec((tile,), lambda i: (i,)),
-                  pl.BlockSpec((tile,), lambda i: (i,)),
-                  pl.BlockSpec((tile,), lambda i: (i,)),
-                  pl.BlockSpec((nflows,), full),
-                  pl.BlockSpec((nflows,), full),
-                  pl.BlockSpec((nflows,), full),
-                  pl.BlockSpec((nflows,), full)],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((mp,), _I32),
-        interpret=_interpret(),
-    )(bins.astype(_I32), flow.astype(_I32), offsets.astype(_I32), valid,
-      word_off.astype(_I32), row_words.astype(_I32), caps.astype(_I32),
-      rounds.astype(_I32))
-    return slots[:m]
+        grid=(rows * _LANES // tile,),
+        in_specs=[table] * 4 + [items] * 4,
+        out_specs=items,
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), _I32),
+    )(word_off.astype(_I32), row_words.astype(_I32), caps.astype(_I32),
+      rounds.astype(_I32), dense(bins), dense(flow), dense(offsets),
+      dense(valid))
+    return slots.reshape(-1)[:m]
 
 
 def _pack_rows_kernel(rows_ref, bins_ref, flow_ref, off_ref, valid_ref,
@@ -252,7 +260,7 @@ def pack_rows(rows: jax.Array, bins: jax.Array, flow: jax.Array,
     kern = functools.partial(_pack_rows_kernel, nflows=nflows, rnd=rnd,
                              wtot=wtot, total=total, wmax=wmax)
     full = lambda i: (0,)
-    return pl.pallas_call(
+    return pallas_call(
         kern,
         grid=(mp // tile,),
         in_specs=[pl.BlockSpec((tile, wmax), lambda i: (i, 0)),
@@ -266,7 +274,6 @@ def pack_rows(rows: jax.Array, bins: jax.Array, flow: jax.Array,
                   pl.BlockSpec((nflows,), full)],
         out_specs=pl.BlockSpec((total,), full),
         out_shape=jax.ShapeDtypeStruct((total,), _U32),
-        interpret=_interpret(),
     )(rows.astype(_U32), bins.astype(_I32), flow.astype(_I32),
       offsets.astype(_I32), valid, word_off.astype(_I32),
       row_words.astype(_I32), caps.astype(_I32), rounds.astype(_I32))
@@ -314,7 +321,7 @@ def place_rows(dst: jax.Array, slots: jax.Array, rows: jax.Array,
     mp = slots.shape[0]
     kern = functools.partial(_place_rows_kernel, total=total, w=w)
     full = lambda i: (0,)
-    return pl.pallas_call(
+    return pallas_call(
         kern,
         grid=(mp // tile,),
         in_specs=[pl.BlockSpec((total,), full),
@@ -322,22 +329,27 @@ def place_rows(dst: jax.Array, slots: jax.Array, rows: jax.Array,
                   pl.BlockSpec((tile, w), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((total,), full),
         out_shape=jax.ShapeDtypeStruct((total,), _U32),
-        interpret=_interpret(),
     )(dst.astype(_U32), slots.astype(_I32), rows.astype(_U32))
 
 
 def _row_mix_kernel(rows_ref, out_ref, *, lanes: int):
     """Per-row wire-checksum hash: weighted lane sum + fmix32 avalanche.
 
-    All arithmetic is wrapping u32 so the kernel is bit-identical to the
-    jnp lowering in ``kernels/ops.py::mix_rows`` (the sender and owner
-    sides of an integrity-checked exchange must agree exactly).
+    The tile is lane-major, (L, TM): rows run along the vector lanes, so
+    a narrow row matrix is not padded out to 128 lanes.  All arithmetic
+    wraps mod 2^32 so the kernel is bit-identical to the jnp lowering in
+    ``kernels/ops.py::mix_rows`` (the sender and owner sides of an
+    integrity-checked exchange must agree exactly); Mosaic reduces only
+    signed integers, so the lane sum runs on the i32 bit pattern, which
+    wraps identically.
     """
-    rows = rows_ref[...].astype(_U32)                    # (TM, L)
+    rows = rows_ref[...].astype(_U32)                    # (L, TM)
     mult = (_U32(0x9E3779B1)
-            * (jax.lax.broadcasted_iota(_U32, (1, lanes), 1) * _U32(2)
+            * (jax.lax.broadcasted_iota(_U32, (lanes, 1), 0) * _U32(2)
                + _U32(1)))
-    h = jnp.sum(rows * mult, axis=1, dtype=_U32)
+    h = jax.lax.bitcast_convert_type(
+        jnp.sum(jax.lax.bitcast_convert_type(rows * mult, _I32), axis=0,
+                keepdims=True), _U32)                    # (1, TM)
     h = h ^ (h >> 16)
     h = h * _U32(0x85EBCA6B)
     h = h ^ (h >> 13)
@@ -354,15 +366,14 @@ def row_mix(rows: jax.Array, tile: int = 2048) -> jax.Array:
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
     mp = rows.shape[0]
     kern = functools.partial(_row_mix_kernel, lanes=lanes)
-    out = pl.pallas_call(
+    out = pallas_call(
         kern,
         grid=(mp // tile,),
-        in_specs=[pl.BlockSpec((tile, lanes), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((mp,), _U32),
-        interpret=_interpret(),
-    )(rows.astype(_U32))
-    return out[:m]
+        in_specs=[pl.BlockSpec((lanes, tile), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, mp), _U32),
+    )(rows.astype(_U32).T)
+    return out[0, :m]
 
 
 def histogram(bins: jax.Array, nbins: int, valid: jax.Array | None = None,
@@ -377,12 +388,11 @@ def histogram(bins: jax.Array, nbins: int, valid: jax.Array | None = None,
         valid = jnp.pad(valid, (0, pad))
     mp = bins.shape[0]
     kern = functools.partial(_hist_kernel, nbins=nbins)
-    return pl.pallas_call(
+    return pallas_call(
         kern,
         grid=(mp // tile,),
         in_specs=[pl.BlockSpec((tile,), lambda i: (i,)),
                   pl.BlockSpec((tile,), lambda i: (i,))],
         out_specs=pl.BlockSpec((nbins,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((nbins,), _I32),
-        interpret=_interpret(),
     )(bins.astype(_I32), valid)

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import interpret_mode as _interpret
+from repro.kernels import pallas_call
 
 _F32 = jnp.float32
 _NEG_INF = -1e30
@@ -108,7 +108,7 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
         causal=causal, window=(window if window > 0 else (tk if not causal else 0)),
         bq=bq, bk=bk, tq=tq, tk=tk)
 
-    out = pl.pallas_call(
+    out = pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -125,6 +125,5 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), _F32),    # running normalizer
             pltpu.VMEM((bq, d), _F32),    # output accumulator
         ],
-        interpret=_interpret(),
     )(q, k, v)
     return out[:, :, :tq]

@@ -1,27 +1,33 @@
 """Pallas TPU kernel: blocked open-addressing hash probe (insert + find).
 
 TPU adaptation of the paper's hash bucket probing (DESIGN.md section 2).
-The table is an array of blocks of B buckets; a query compares against
-all B slots of its block in one vector op.  Queries are pre-binned per
-block on the host side (the same machinery as the exchange engine), so
-the kernel's addressing is entirely tile-local:
+The table is an array of blocks of B buckets, stored lane-major: a
+block's key lane ``l`` is one B-wide vector row (``tkeys[b, l, :]``), so
+a query compares against all B slots of its block in one vector op and
+the table's HBM layout is dense (B = 128 fills the vector lanes).
+Queries are pre-binned per block on the host side (the same machinery as
+the exchange engine), so the kernel's addressing is entirely tile-local:
 
   grid         (nb / TB,)                    one step per tile of blocks
-  tkeys tile   (TB, B, Lk)  VMEM             the table tile
-  query tile   (TB, Q, Lk)  VMEM             binned queries
+  table tile   (TB, Lk, B) / (TB, Lv, B)     keys / values
+  status tile  (TB, B)
+  query tile   (TB, Lq * Q)                  binned queries: lane l of
+                                             query j in column l*Q + j
 
-Insert iterates the Q binned queries of each block sequentially (the
-deterministic arrival order — the ownership-serialized analogue of the
-paper's CAS loop) while staying fully vectorized across the TB blocks
-of the tile and the B slots of each block.  All slot updates use
-one-hot selects rather than scatters — the VPU-friendly formulation.
+Both ops iterate the Q binned queries of each block in order (for
+insert, the deterministic arrival order — the ownership-serialized
+analogue of the paper's CAS loop) while staying vectorized across the
+TB blocks of the tile and the B slots of each block.  Mosaic has no
+scatter, gather, cumsum or dynamic slice of a loaded value, so every
+step is selects and lane reductions: column ``c`` of the query tile is a
+one-hot select plus lane sum, the first matching (or free) slot is the
+minimum of a masked lane iota, and a slot's value is a one-hot select
+plus lane sum.  Mosaic reduces only signed integers; the sums run on the
+i32 bit pattern of u32 lanes, exact because exactly one term is nonzero.
 
-Find has no ordering constraint and is a single (TB, Q, B) compare +
-one-hot value contraction (MXU matmul shape).
-
-VMEM budget at defaults (TB=8, B=128, Q=64, Lk+Lv=4 lanes, u32):
-8*128*4*4 B (table) + 8*64*4*4 B (queries) ~= 24 KiB — comfortably
-inside the ~16 MiB/core VMEM with room for double buffering.
+VMEM per tile at TB=64, B=128, Lk=Lv=2 (u32, second-minor padded to 8):
+2 * 64*8*128*4 B (keys + values) + 64*128*4 B (status) ~= 544 KiB per
+buffer, well inside the scoped VMEM limit with in/out double buffering.
 """
 
 from __future__ import annotations
@@ -32,8 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import interpret_mode as _interpret
-from repro.kernels.ref import MODE_SET, MODE_ADD, MODE_KEEP
+from repro.kernels import pallas_call
+from repro.kernels.ref import MODE_SET, MODE_ADD, hash_probe_find_ref
 
 # kernel-local constants (plain ints: Pallas kernels cannot capture arrays)
 _FREE, _READY, _MASK = 0, 2, 3
@@ -54,12 +60,12 @@ def bin_queries(qblock, qvalid, nb: int, q_cap: int):
     """
     m = qblock.shape[0]
     b = jnp.where(qvalid, qblock.astype(_I32), nb)
-    counts_full = jnp.zeros((nb + 1,), _I32).at[b].add(1)
-    start = jnp.concatenate([jnp.zeros((1,), _I32),
-                             jnp.cumsum(counts_full)[:-1].astype(_I32)])
     order = jnp.argsort(b, stable=True)
     sortb = b[order]
-    pos = jnp.arange(m, dtype=_I32) - start[sortb]
+    # rank within the block = sorted position - the block's first sorted
+    # position (a binary search, not a prefix sum over all nb blocks)
+    first = jnp.searchsorted(sortb, sortb, side="left").astype(_I32)
+    pos = jnp.arange(m, dtype=_I32) - first
     pos_orig = jnp.zeros((m,), _I32).at[order].set(pos)
     overflow = qvalid & (pos_orig >= q_cap)
     ok = qvalid & ~overflow
@@ -67,11 +73,22 @@ def bin_queries(qblock, qvalid, nb: int, q_cap: int):
     return slot, overflow
 
 
-def _scatter_to_bins(x, slot, nb, q_cap, lanes):
-    out = jnp.zeros((nb * q_cap, lanes), _U32)
-    if x.ndim == 1:
-        x = x[:, None]
-    return out.at[slot].set(x.astype(_U32), mode="drop").reshape(nb, q_cap, lanes)
+def _bin_rows(rows, slot, nb: int, q_cap: int):
+    """Scatter (M, L) u32 rows into the (nb, L*q_cap) query tiles.
+
+    Lane ``l`` of the query in bin slot ``b*q_cap + j`` lands in row
+    ``b``, column ``l*q_cap + j``; rows with the drop slot vanish.
+    """
+    lanes = rows.shape[1]
+    width = lanes * q_cap
+    blk, pos = slot // q_cap, slot % q_cap
+    # (L, M) lane-major indices: no (M, L) index array padded on TPU
+    col = jnp.arange(lanes, dtype=_I32)[:, None] * q_cap + pos[None, :]
+    idx = jnp.where((slot < nb * q_cap)[None, :], blk[None, :] * width + col,
+                    nb * width)
+    out = jnp.zeros((nb * width,), _U32).at[idx.reshape(-1)].set(
+        rows.astype(_U32).T.reshape(-1), mode="drop")
+    return out.reshape(nb, width)
 
 
 def default_q_cap(m: int, nb: int) -> int:
@@ -80,55 +97,167 @@ def default_q_cap(m: int, nb: int) -> int:
     return int(min(m, max(16, 8 * avg)))
 
 
+def _tile_blocks(nb: int) -> int:
+    """Blocks per grid step: a multiple of 8 (the sublane tiling of the
+    status and query tiles) that divides ``nb``, else the whole table."""
+    for tb in (64, 32, 16, 8):
+        if nb % tb == 0:
+            return tb
+    return nb
+
+
+# --------------------------------------------------------------------------
+# in-kernel helpers
+# --------------------------------------------------------------------------
+
+def _as_i32(x):
+    return jax.lax.bitcast_convert_type(x, _I32)
+
+
+def _as_u32(x):
+    return jax.lax.bitcast_convert_type(x, _U32)
+
+
+def _lane_pick(x_i32, mask):
+    """(T, 1) sum of the masked lanes of an i32 tile (one lane set)."""
+    return jnp.sum(jnp.where(mask, x_i32, 0), axis=1, keepdims=True)
+
+
+def _first_lane(mask, lane, width: int):
+    """(T, 1) index of each row's first set lane; ``width`` when none."""
+    return jnp.min(jnp.where(mask, lane, width), axis=1, keepdims=True)
+
+
 # --------------------------------------------------------------------------
 # insert kernel
 # --------------------------------------------------------------------------
 
-def _insert_kernel(tk_ref, tv_ref, st_ref, qk_ref, qv_ref, qval_ref,
+def _insert_kernel(tk_ref, tv_ref, st_ref, q_ref,
                    otk_ref, otv_ref, ost_ref, ok_ref, *, mode: int,
-                   q_cap: int, block_size: int):
-    tk = tk_ref[...]          # (TB, B, Lk)
-    tv = tv_ref[...]          # (TB, B, Lv)
-    st = st_ref[...]          # (TB, B)
-    tb = tk.shape[0]
+                   q_cap: int, lk: int, lv: int):
+    """Ownership-serialized insert of one tile's binned queries.
+
+    Query columns: key lanes, value lanes, validity (``lk + lv + 1``
+    lanes of ``q_cap`` columns each).  The tile is read once; the query
+    loop carries only what it wrote — a mask of written slots and their
+    key and value lanes, all starting at zero — and the tile is stored
+    once.  (A carry that starts as loaded data would be typed apart from
+    the loop body under ``check_vma`` when the kernel is interpreted.)
+    """
+    tk0 = [tk_ref[:, l, :] for l in range(lk)]
+    tv0 = [tv_ref[:, l, :] for l in range(lv)]
+    st0 = st_ref[...]
+    ready_st = (st0 & _U32(~_MASK & 0xFFFFFFFF)) | _U32(_READY)
+    q = _as_i32(q_ref[...])                       # (TB, Lq*Q)
+    tb, bsz = st0.shape
+    qlane = jax.lax.broadcasted_iota(_I32, q.shape, 1)
+    slot_lane = jax.lax.broadcasted_iota(_I32, (tb, bsz), 1)
+    ok_lane = jax.lax.broadcasted_iota(_I32, (tb, q_cap), 1)
+
+    def column(c):
+        return _lane_pick(q, qlane == c)          # (TB, 1) i32
 
     def body(j, carry):
-        tk, tv, st, ok = carry
-        key = jax.lax.dynamic_slice_in_dim(qk_ref[...], j, 1, axis=1)[:, 0]
-        val = jax.lax.dynamic_slice_in_dim(qv_ref[...], j, 1, axis=1)[:, 0]
-        vld = jax.lax.dynamic_slice_in_dim(qval_ref[...], j, 1, axis=1)[:, 0]
-        state = st & _MASK
-        match = (tk == key[:, None, :]).all(axis=2) & (state == _READY)
-        has_match = match.any(axis=1)
-        free = state == _FREE
-        has_free = free.any(axis=1)
-        # first-match / first-free via argmax on bool
-        mslot = jnp.argmax(match, axis=1)
-        fslot = jnp.argmax(free, axis=1)
+        wrote, wtk, wtv, ok = carry
+        written = wrote == 1
+        tks = [jnp.where(written, wtk[l], tk0[l]) for l in range(lk)]
+        tvs = [jnp.where(written, wtv[l], tv0[l]) for l in range(lv)]
+        key = [_as_u32(column(l * q_cap + j)) for l in range(lk)]
+        val = [_as_u32(column((lk + l) * q_cap + j)) for l in range(lv)]
+        vld = column((lk + lv) * q_cap + j) == 1
+        state = jnp.where(written, ready_st, st0) & _U32(_MASK)
+        match = state == _U32(_READY)
+        for l in range(lk):
+            match = match & (tks[l] == key[l])
+        mslot = _first_lane(match, slot_lane, bsz)
+        fslot = _first_lane(state == _U32(_FREE), slot_lane, bsz)
+        has_match = mslot < bsz
         slot = jnp.where(has_match, mslot, fslot)
-        can = (vld == 1) & (has_match | has_free)
+        can = vld & (slot < bsz)
+        at = slot_lane == slot                    # (TB, B)
+        put = at & can
+        new_tv = []
+        for l in range(lv):
+            if mode == MODE_SET:
+                new = val[l]
+            else:
+                old = _as_u32(_lane_pick(_as_i32(tvs[l]), at))
+                new = jnp.where(has_match,
+                                old + val[l] if mode == MODE_ADD else old,
+                                val[l])
+            new_tv.append(jnp.where(put, new, tvs[l]))
+        return (jnp.where(put, 1, wrote),
+                [jnp.where(put, key[l], tks[l]) for l in range(lk)], new_tv,
+                jnp.where(ok_lane == j, can.astype(_I32), ok))
 
-        onehot = (jax.lax.broadcasted_iota(_I32, (tb, block_size), 1)
-                  == slot[:, None]) & can[:, None]
-        old_val = jnp.take_along_axis(tv, slot[:, None, None], axis=1)[:, 0]
-        if mode == MODE_ADD:
-            new_val = jnp.where(has_match[:, None], old_val + val, val)
-        elif mode == MODE_KEEP:
-            new_val = jnp.where(has_match[:, None], old_val, val)
-        else:
-            new_val = val
-        tk = jnp.where(onehot[:, :, None], key[:, None, :], tk)
-        tv = jnp.where(onehot[:, :, None], new_val[:, None, :], tv)
-        st = jnp.where(onehot, (st & ~_U32(_MASK)) | _U32(_READY), st)
-        ok = ok.at[:, j].set(can)
-        return tk, tv, st, ok
+    zeros = jnp.zeros((tb, bsz), _U32)
+    wrote, wtk, wtv, ok = jax.lax.fori_loop(
+        0, q_cap, body, (jnp.zeros((tb, bsz), _I32), [zeros] * lk,
+                         [zeros] * lv, jnp.zeros((tb, q_cap), _I32)))
+    written = wrote == 1
+    for l in range(lk):
+        otk_ref[:, l, :] = jnp.where(written, wtk[l], tk0[l])
+    for l in range(lv):
+        otv_ref[:, l, :] = jnp.where(written, wtv[l], tv0[l])
+    ost_ref[...] = jnp.where(written, ready_st, st0)
+    ok_ref[...] = ok
 
-    ok0 = jnp.zeros((tb, q_cap), bool)
-    tk, tv, st, ok = jax.lax.fori_loop(0, q_cap, body, (tk, tv, st, ok0))
-    otk_ref[...] = tk
-    otv_ref[...] = tv
-    ost_ref[...] = st
-    ok_ref[...] = ok.astype(_U32)
+
+def insert_binned(tkeys, tvals, status, qbins, mode: int, q_cap: int,
+                  tile_blocks: int | None = None):
+    """The insert kernel over binned query tiles.
+
+    ``qbins`` is (nb, (Lk+Lv+1)*q_cap) u32 as :func:`_bin_rows` lays it
+    out (key lanes, value lanes, validity).  Returns the updated table
+    and the (nb, q_cap) i32 per-bin success flags.
+    """
+    nb, lk, bsz = tkeys.shape
+    lv = tvals.shape[1]
+    tb = tile_blocks or _tile_blocks(nb)
+    width = qbins.shape[1]
+    kern = functools.partial(_insert_kernel, mode=mode, q_cap=q_cap,
+                             lk=lk, lv=lv)
+    return pallas_call(
+        kern,
+        grid=(nb // tb,),
+        in_specs=[
+            pl.BlockSpec((tb, lk, bsz), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tb, lv, bsz), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tb, bsz), lambda i: (i, 0)),
+            pl.BlockSpec((tb, width), lambda i: (i, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((tb, lk, bsz), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tb, lv, bsz), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tb, bsz), lambda i: (i, 0)),
+            pl.BlockSpec((tb, q_cap), lambda i: (i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((nb, lk, bsz), _U32),
+            jax.ShapeDtypeStruct((nb, lv, bsz), _U32),
+            jax.ShapeDtypeStruct((nb, bsz), _U32),
+            jax.ShapeDtypeStruct((nb, q_cap), _I32),
+        ],
+        input_output_aliases={0: 0, 1: 1, 2: 2},
+    )(tkeys.astype(_U32), tvals.astype(_U32), status.astype(_U32), qbins)
+
+
+def _insert_rows(tkeys, tvals, status, qblock, rows, valid, mode: int,
+                 q_cap: int | None, tile_blocks: int | None):
+    """Bin (M, Lk+Lv) key|value rows per block and run the insert kernel."""
+    nb = tkeys.shape[0]
+    m = qblock.shape[0]
+    q_cap = q_cap or default_q_cap(m, nb)
+    slot, overflow = bin_queries(qblock, valid, nb, q_cap)
+    comb = jnp.concatenate([rows.astype(_U32), valid.astype(_U32)[:, None]],
+                           axis=1)
+    otk, otv, ost, okbins = insert_binned(
+        tkeys, tvals, status, _bin_rows(comb, slot, nb, q_cap), mode, q_cap,
+        tile_blocks)
+    flat_ok = okbins.reshape(-1)
+    take = jnp.minimum(slot, nb * q_cap - 1)
+    success = jnp.where(slot < nb * q_cap, flat_ok[take] == 1, False)
+    return otk, otv, ost, success & ~overflow & valid
 
 
 def insert(tkeys, tvals, status, qblock, qkeys, qvals, qvalid,
@@ -139,108 +268,9 @@ def insert(tkeys, tvals, status, qblock, qkeys, qvals, qvalid,
     Items that overflow a block's static query capacity fail (success
     False) exactly like a full block — callers already retry those.
     """
-    nb, bsz, lk = tkeys.shape
-    lv = tvals.shape[2]
-    m = qblock.shape[0]
-    q_cap = q_cap or default_q_cap(m, nb)
-    tb = tile_blocks or (8 if nb % 8 == 0 else 1)
-
-    slot, overflow = bin_queries(qblock, qvalid, nb, q_cap)
-    qk = _scatter_to_bins(qkeys, slot, nb, q_cap, lk)
-    qv = _scatter_to_bins(qvals, slot, nb, q_cap, lv)
-    qval = _scatter_to_bins(qvalid.astype(_U32), slot, nb, q_cap, 1)[..., 0]
-
-    grid = (nb // tb,)
-    kern = functools.partial(_insert_kernel, mode=mode, q_cap=q_cap,
-                             block_size=bsz)
-    otk, otv, ost, okbins = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tb, bsz, lk), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz, lv), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz), lambda i: (i, 0)),
-            pl.BlockSpec((tb, q_cap, lk), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, q_cap, lv), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, q_cap), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tb, bsz, lk), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz, lv), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz), lambda i: (i, 0)),
-            pl.BlockSpec((tb, q_cap), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, bsz, lk), _U32),
-            jax.ShapeDtypeStruct((nb, bsz, lv), _U32),
-            jax.ShapeDtypeStruct((nb, bsz), _U32),
-            jax.ShapeDtypeStruct((nb, q_cap), _U32),
-        ],
-        interpret=_interpret(),
-    )(tkeys, tvals, status, qk, qv, qval)
-
-    flat_ok = okbins.reshape(-1)
-    success = jnp.zeros((m,), bool)
-    take = jnp.minimum(slot, nb * q_cap - 1)
-    success = jnp.where(slot < nb * q_cap, flat_ok[take] == 1, False)
-    success = success & ~overflow & qvalid
-    return otk, otv, ost, success
-
-
-def _insert_arrivals_kernel(tk_ref, tv_ref, st_ref, comb_ref,
-                            otk_ref, otv_ref, ost_ref, ok_ref, *, mode: int,
-                            q_cap: int, block_size: int, lk: int, lv: int):
-    """Insert straight off the combined arrival tile (DESIGN.md §1.10).
-
-    ``comb_ref`` holds one (TB, Q, lk+lv+1) tile of the wire's arrival
-    rows — key lanes, value lanes, validity — binned by ONE scatter on
-    the host side instead of one per component; the kernel slices the
-    columns (static slices on the VMEM block, free) and then runs the
-    exact :func:`_insert_kernel` ownership-serialized loop.
-    """
-    tk = tk_ref[...]          # (TB, B, Lk)
-    tv = tv_ref[...]          # (TB, B, Lv)
-    st = st_ref[...]          # (TB, B)
-    tb = tk.shape[0]
-
-    def body(j, carry):
-        tk, tv, st, ok = carry
-        row = jax.lax.dynamic_slice_in_dim(comb_ref[...], j, 1,
-                                           axis=1)[:, 0]  # (TB, L)
-        key = row[:, :lk]
-        val = row[:, lk:lk + lv]
-        vld = row[:, lk + lv]
-        state = st & _MASK
-        match = (tk == key[:, None, :]).all(axis=2) & (state == _READY)
-        has_match = match.any(axis=1)
-        free = state == _FREE
-        has_free = free.any(axis=1)
-        mslot = jnp.argmax(match, axis=1)
-        fslot = jnp.argmax(free, axis=1)
-        slot = jnp.where(has_match, mslot, fslot)
-        can = (vld == 1) & (has_match | has_free)
-
-        onehot = (jax.lax.broadcasted_iota(_I32, (tb, block_size), 1)
-                  == slot[:, None]) & can[:, None]
-        old_val = jnp.take_along_axis(tv, slot[:, None, None], axis=1)[:, 0]
-        if mode == MODE_ADD:
-            new_val = jnp.where(has_match[:, None], old_val + val, val)
-        elif mode == MODE_KEEP:
-            new_val = jnp.where(has_match[:, None], old_val, val)
-        else:
-            new_val = val
-        tk = jnp.where(onehot[:, :, None], key[:, None, :], tk)
-        tv = jnp.where(onehot[:, :, None], new_val[:, None, :], tv)
-        st = jnp.where(onehot, (st & ~_U32(_MASK)) | _U32(_READY), st)
-        ok = ok.at[:, j].set(can)
-        return tk, tv, st, ok
-
-    ok0 = jnp.zeros((tb, q_cap), bool)
-    tk, tv, st, ok = jax.lax.fori_loop(0, q_cap, body, (tk, tv, st, ok0))
-    otk_ref[...] = tk
-    otv_ref[...] = tv
-    ost_ref[...] = st
-    ok_ref[...] = ok.astype(_U32)
+    rows = jnp.concatenate([qkeys.astype(_U32), qvals.astype(_U32)], axis=1)
+    return _insert_rows(tkeys, tvals, status, qblock, rows, qvalid, mode,
+                        q_cap, tile_blocks)
 
 
 def insert_arrivals(tkeys, tvals, status, seg, valid,
@@ -250,157 +280,122 @@ def insert_arrivals(tkeys, tvals, status, seg, valid,
 
     ``seg`` is the exchange wire's (M, 1+Lk+Lv) owner view — local
     block, key lanes, value lanes — exactly as sliced off the arrival
-    buffer.  Semantics == :func:`insert` on the sliced columns, but the
-    host side bins with ONE combined scatter instead of three, so the
-    arrivals cross HBM once before the probe.
+    buffer.  Semantics == :func:`insert` on the sliced columns; the key
+    and value lanes are binned together, by one scatter.
     """
-    nb, bsz, lk = tkeys.shape
-    lv = tvals.shape[2]
-    m = seg.shape[0]
-    q_cap = q_cap or default_q_cap(m, nb)
-    tb = tile_blocks or (8 if nb % 8 == 0 else 1)
-
+    lk, lv = tkeys.shape[1], tvals.shape[1]
     qblock = jnp.where(valid, seg[:, 0].astype(_I32), 0)
-    slot, overflow = bin_queries(qblock, valid, nb, q_cap)
-    comb = jnp.concatenate([seg[:, 1:1 + lk + lv].astype(_U32),
-                            valid.astype(_U32)[:, None]], axis=1)
-    cb = _scatter_to_bins(comb, slot, nb, q_cap, lk + lv + 1)
-
-    grid = (nb // tb,)
-    kern = functools.partial(_insert_arrivals_kernel, mode=mode, q_cap=q_cap,
-                            block_size=bsz, lk=lk, lv=lv)
-    otk, otv, ost, okbins = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tb, bsz, lk), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz, lv), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz), lambda i: (i, 0)),
-            pl.BlockSpec((tb, q_cap, lk + lv + 1), lambda i: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tb, bsz, lk), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz, lv), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz), lambda i: (i, 0)),
-            pl.BlockSpec((tb, q_cap), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, bsz, lk), _U32),
-            jax.ShapeDtypeStruct((nb, bsz, lv), _U32),
-            jax.ShapeDtypeStruct((nb, bsz), _U32),
-            jax.ShapeDtypeStruct((nb, q_cap), _U32),
-        ],
-        interpret=_interpret(),
-    )(tkeys, tvals, status, cb)
-
-    flat_ok = okbins.reshape(-1)
-    take = jnp.minimum(slot, nb * q_cap - 1)
-    success = jnp.where(slot < nb * q_cap, flat_ok[take] == 1, False)
-    success = success & ~overflow & valid
-    return otk, otv, ost, success
+    return _insert_rows(tkeys, tvals, status, qblock, seg[:, 1:1 + lk + lv],
+                        valid, mode, q_cap, tile_blocks)
 
 
 # --------------------------------------------------------------------------
 # find kernel
 # --------------------------------------------------------------------------
 
-def _find_kernel(tk_ref, tv_ref, st_ref, qk_ref, qval_ref,
-                 found_ref, val_ref, *, block_size: int):
-    tk = tk_ref[...]                      # (TB, B, Lk)
-    tv = tv_ref[...]                      # (TB, B, Lv)
-    st = st_ref[...]                      # (TB, B)
-    qk = qk_ref[...]                      # (TB, Q, Lk)
-    vld = qval_ref[...] == 1              # (TB, Q)
+def _find_kernel(tk_ref, tv_ref, st_ref, q_ref, out_ref, *, q_cap: int,
+                 lk: int, lv: int):
+    """Probe one tile's binned queries (key lanes + validity columns).
 
-    ready = (st & _MASK) == _READY        # (TB, B)
-    match = (qk[:, :, None, :] == tk[:, None, :, :]).all(axis=3)
-    match = match & ready[:, None, :]     # (TB, Q, B)
-    found = match.any(axis=2) & vld
-    # first matching slot, recovered via an integer gather (u32 values
-    # would not survive an f32 MXU contraction above 2^24)
-    first = match & (jnp.cumsum(match.astype(_I32), axis=2) == 1)
-    slot = jnp.argmax(first, axis=2)      # (TB, Q)
-    vals_exact = jnp.take_along_axis(tv, slot[:, :, None], axis=1)
-    found_ref[...] = found.astype(_U32)
-    val_ref[...] = jnp.where(found[:, :, None], vals_exact, 0)
+    The output tile holds, per block, the value lanes of each query in
+    columns ``l*Q + j`` and its found flag in column ``lv*Q + j``.
+    """
+    q = _as_i32(q_ref[...])                       # (TB, (Lk+1)*Q)
+    st = st_ref[...]
+    tb, bsz = st.shape
+    ready = (st & _U32(_MASK)) == _U32(_READY)
+    keys = [tk_ref[:, l, :] for l in range(lk)]
+    vals = [_as_i32(tv_ref[:, l, :]) for l in range(lv)]
+    qlane = jax.lax.broadcasted_iota(_I32, q.shape, 1)
+    slot_lane = jax.lax.broadcasted_iota(_I32, (tb, bsz), 1)
+    out_lane = jax.lax.broadcasted_iota(_I32, out_ref.shape, 1)
+
+    def body(j, acc):
+        match = ready
+        for l in range(lk):
+            key = _as_u32(_lane_pick(q, qlane == l * q_cap + j))
+            match = match & (keys[l] == key)
+        vld = _lane_pick(q, qlane == lk * q_cap + j) == 1
+        mslot = _first_lane(match, slot_lane, bsz)
+        found = vld & (mslot < bsz)
+        at = (slot_lane == mslot) & found
+        for l in range(lv):
+            acc = jnp.where(out_lane == l * q_cap + j,
+                            _lane_pick(vals[l], at), acc)
+        return jnp.where(out_lane == lv * q_cap + j, found.astype(_I32), acc)
+
+    out_ref[...] = _as_u32(jax.lax.fori_loop(
+        0, q_cap, body, jnp.zeros(out_ref.shape, _I32)))
+
+
+def find_binned(tkeys, tvals, status, qbins, q_cap: int,
+                tile_blocks: int | None = None):
+    """The find kernel over binned query tiles.
+
+    ``qbins`` is (nb, (Lk+1)*q_cap) u32 (key lanes, validity); returns
+    (nb, (Lv+1)*q_cap) u32: value lanes, then the found flags.
+    """
+    nb, lk, bsz = tkeys.shape
+    lv = tvals.shape[1]
+    tb = tile_blocks or _tile_blocks(nb)
+    width = qbins.shape[1]
+    owidth = (lv + 1) * q_cap
+    kern = functools.partial(_find_kernel, q_cap=q_cap, lk=lk, lv=lv)
+    return pallas_call(
+        kern,
+        grid=(nb // tb,),
+        in_specs=[
+            pl.BlockSpec((tb, lk, bsz), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tb, lv, bsz), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tb, bsz), lambda i: (i, 0)),
+            pl.BlockSpec((tb, width), lambda i: (i, 0)),
+        ],
+        out_specs=pl.BlockSpec((tb, owidth), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, owidth), _U32),
+    )(tkeys.astype(_U32), tvals.astype(_U32), status.astype(_U32), qbins)
+
+
+def _find_keys(tkeys, tvals, status, qblock, qkeys, valid,
+               q_cap: int | None, tile_blocks: int | None):
+    """Bin (M, Lk) key rows per block and run the find kernel."""
+    nb = tkeys.shape[0]
+    lv = tvals.shape[1]
+    m = qblock.shape[0]
+    q_cap = q_cap or default_q_cap(m, nb)
+    slot, overflow = bin_queries(qblock, valid, nb, q_cap)
+    comb = jnp.concatenate([qkeys.astype(_U32),
+                            (valid & ~overflow).astype(_U32)[:, None]],
+                           axis=1)
+    res = find_binned(tkeys, tvals, status, _bin_rows(comb, slot, nb, q_cap),
+                      q_cap, tile_blocks)
+
+    in_range = slot < nb * q_cap
+    blk = jnp.minimum(slot // q_cap, nb - 1)
+    pos = slot % q_cap
+    cols = jnp.arange(lv + 1, dtype=_I32)[:, None] * q_cap + pos[None, :]
+    got = res.reshape(-1)[blk[None, :] * ((lv + 1) * q_cap) + cols]
+    found = in_range & (got[lv] == 1) & valid & ~overflow   # got: (Lv+1, M)
+    vals = jnp.where(found[:, None], got[:lv].T, 0)
+
+    # overflow queries take the direct jnp probe (rare, bounded); it runs
+    # only when some query overflowed its block's bin
+    def probe_overflow(_):
+        return hash_probe_find_ref(tkeys, tvals, status,
+                                   jnp.clip(qblock, 0, nb - 1), qkeys,
+                                   overflow)
+
+    def none(_):
+        return jnp.zeros_like(found), jnp.zeros_like(vals)
+
+    f2, v2 = jax.lax.cond(overflow.any(), probe_overflow, none, None)
+    return found | f2, jnp.where(f2[:, None], v2, vals)
 
 
 def find(tkeys, tvals, status, qblock, qkeys, qvalid,
          q_cap: int | None = None, tile_blocks: int | None = None):
     """Pallas bulk find; semantics == ref.hash_probe_find_ref."""
-    nb, bsz, lk = tkeys.shape
-    lv = tvals.shape[2]
-    m = qblock.shape[0]
-    q_cap = q_cap or default_q_cap(m, nb)
-    tb = tile_blocks or (8 if nb % 8 == 0 else 1)
-
-    slot, overflow = bin_queries(qblock, qvalid, nb, q_cap)
-    qk = _scatter_to_bins(qkeys, slot, nb, q_cap, lk)
-    qval = _scatter_to_bins((qvalid & ~overflow).astype(_U32), slot,
-                            nb, q_cap, 1)[..., 0]
-
-    grid = (nb // tb,)
-    kern = functools.partial(_find_kernel, block_size=bsz)
-    foundb, valb = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tb, bsz, lk), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz, lv), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz), lambda i: (i, 0)),
-            pl.BlockSpec((tb, q_cap, lk), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, q_cap), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tb, q_cap), lambda i: (i, 0)),
-            pl.BlockSpec((tb, q_cap, lv), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, q_cap), _U32),
-            jax.ShapeDtypeStruct((nb, q_cap, lv), _U32),
-        ],
-        interpret=_interpret(),
-    )(tkeys, tvals, status, qk, qval)
-
-    flat_f = foundb.reshape(-1)
-    flat_v = valb.reshape(-1, lv)
-    take = jnp.minimum(slot, nb * q_cap - 1)
-    in_range = slot < nb * q_cap
-    found = jnp.where(in_range, flat_f[take] == 1, False) & qvalid & ~overflow
-    vals = jnp.where(found[:, None], flat_v[take], 0)
-
-    # overflow queries fall back to the direct jnp probe (rare, bounded)
-    if True:
-        from repro.kernels.ref import hash_probe_find_ref
-        f2, v2 = hash_probe_find_ref(tkeys, tvals, status,
-                                     jnp.clip(qblock, 0, nb - 1), qkeys,
-                                     overflow)
-        found = found | f2
-        vals = jnp.where(f2[:, None], v2, vals)
-    return found, vals
-
-
-def _find_arrivals_kernel(tk_ref, tv_ref, st_ref, comb_ref,
-                          found_ref, val_ref, *, block_size: int, lk: int):
-    """:func:`_find_kernel` off the combined (TB, Q, lk+1) arrival tile:
-    key lanes + validity binned by one host-side scatter, columns split
-    in-kernel (static VMEM slices)."""
-    tk = tk_ref[...]                      # (TB, B, Lk)
-    tv = tv_ref[...]                      # (TB, B, Lv)
-    st = st_ref[...]                      # (TB, B)
-    comb = comb_ref[...]                  # (TB, Q, Lk+1)
-    qk = comb[:, :, :lk]
-    vld = comb[:, :, lk] == 1             # (TB, Q)
-
-    ready = (st & _MASK) == _READY        # (TB, B)
-    match = (qk[:, :, None, :] == tk[:, None, :, :]).all(axis=3)
-    match = match & ready[:, None, :]     # (TB, Q, B)
-    found = match.any(axis=2) & vld
-    first = match & (jnp.cumsum(match.astype(_I32), axis=2) == 1)
-    slot = jnp.argmax(first, axis=2)      # (TB, Q)
-    vals_exact = jnp.take_along_axis(tv, slot[:, :, None], axis=1)
-    found_ref[...] = found.astype(_U32)
-    val_ref[...] = jnp.where(found[:, :, None], vals_exact, 0)
+    return _find_keys(tkeys, tvals, status, qblock, qkeys, qvalid, q_cap,
+                      tile_blocks)
 
 
 def find_arrivals(tkeys, tvals, status, seg, valid,
@@ -409,54 +404,9 @@ def find_arrivals(tkeys, tvals, status, seg, valid,
 
     ``seg`` is the wire's (M, 1+Lk) owner view (local block + key
     lanes); results are bit-identical to :func:`find` on the sliced
-    columns, with the arrivals binned by ONE combined scatter.
+    columns.
     """
-    nb, bsz, lk = tkeys.shape
-    lv = tvals.shape[2]
-    m = seg.shape[0]
-    q_cap = q_cap or default_q_cap(m, nb)
-    tb = tile_blocks or (8 if nb % 8 == 0 else 1)
-
+    lk = tkeys.shape[1]
     qblock = jnp.where(valid, seg[:, 0].astype(_I32), 0)
-    qkeys = seg[:, 1:1 + lk]
-    slot, overflow = bin_queries(qblock, valid, nb, q_cap)
-    comb = jnp.concatenate([qkeys.astype(_U32),
-                            (valid & ~overflow).astype(_U32)[:, None]],
-                           axis=1)
-    cb = _scatter_to_bins(comb, slot, nb, q_cap, lk + 1)
-
-    grid = (nb // tb,)
-    kern = functools.partial(_find_arrivals_kernel, block_size=bsz, lk=lk)
-    foundb, valb = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tb, bsz, lk), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz, lv), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tb, bsz), lambda i: (i, 0)),
-            pl.BlockSpec((tb, q_cap, lk + 1), lambda i: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tb, q_cap), lambda i: (i, 0)),
-            pl.BlockSpec((tb, q_cap, lv), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, q_cap), _U32),
-            jax.ShapeDtypeStruct((nb, q_cap, lv), _U32),
-        ],
-        interpret=_interpret(),
-    )(tkeys, tvals, status, cb)
-
-    flat_f = foundb.reshape(-1)
-    flat_v = valb.reshape(-1, lv)
-    take = jnp.minimum(slot, nb * q_cap - 1)
-    in_range = slot < nb * q_cap
-    found = jnp.where(in_range, flat_f[take] == 1, False) & valid & ~overflow
-    vals = jnp.where(found[:, None], flat_v[take], 0)
-
-    from repro.kernels.ref import hash_probe_find_ref
-    f2, v2 = hash_probe_find_ref(tkeys, tvals, status,
-                                 jnp.clip(qblock, 0, nb - 1), qkeys, overflow)
-    found = found | f2
-    vals = jnp.where(f2[:, None], v2, vals)
-    return found, vals
+    return _find_keys(tkeys, tvals, status, qblock, seg[:, 1:1 + lk], valid,
+                      q_cap, tile_blocks)

@@ -5,10 +5,16 @@ Every op comes in up to three implementations:
   impl="oracle"  sequential-semantics pure-jnp oracle (ref.py)
   impl="jnp"     vectorized pure-jnp (sort + segment ops) — the CPU
                  production path and the second correctness witness
-  impl="pallas"  the Pallas TPU kernel (interpret=True on CPU)
+  impl="pallas"  the Pallas TPU kernel (interpreted off the TPU)
 
 ``impl="auto"`` picks "pallas" on TPU and "jnp" elsewhere.  Containers
 call through this module only.
+
+One declared per-platform exception (DESIGN.md section 1.10): the
+one-pass wire kernels ``binning.pack_rows``/``place_rows`` hold the whole
+(total,) wire as one VMEM block and scatter into it, and Mosaic has no
+scatter, so on TPU :data:`XLA_WIRE_OPS` lower "pallas" to the
+``ragged_slots`` kernel plus XLA's scatter (``scatter_rows``).
 """
 
 from __future__ import annotations
@@ -18,6 +24,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro import kernels as _kernels
 from repro.kernels import ref as _ref
 from repro.kernels.ref import (FREE, READY, STATE_MASK, bucket_state,  # noqa: F401
                                MODE_SET, MODE_ADD, MODE_KEEP)
@@ -26,12 +33,35 @@ _U32 = jnp.uint32
 _I32 = jnp.int32
 
 
+#: ops whose "pallas" impl lowers to ragged_slots + XLA scatter on TPU
+XLA_WIRE_OPS = ("pack_rows", "place_rows")
+
+
 def default_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+    return "pallas" if _kernels.platform() == "tpu" else "jnp"
 
 
 def _resolve(impl: str) -> str:
     return default_impl() if impl == "auto" else impl
+
+
+def _wire_kernel(impl: str) -> bool:
+    """Whether a wire op in :data:`XLA_WIRE_OPS` runs its one-pass kernel."""
+    return impl == "pallas" and _kernels.platform() != "tpu"
+
+
+def lowering(op: str, impl: str = "auto") -> str:
+    """What ``op`` runs as on this platform for ``impl`` (after "auto"),
+    as ``chip_smoke.py`` prints it; DESIGN.md section 1.10 lists it."""
+    impl = _resolve(impl)
+    if op in XLA_WIRE_OPS and impl == "pallas" and not _wire_kernel(impl):
+        return {"pack_rows": "pallas ragged_slots + xla scatter",
+                "place_rows": "xla scatter"}[op]
+    if op == "bloom_find":
+        return "jnp"
+    if op == "bloom_insert" and impl == "pallas":
+        return "pallas membership + xla or-scatter"
+    return impl
 
 
 # --------------------------------------------------------------------------
@@ -43,17 +73,23 @@ def seg_exclusive_or_scan(words: jax.Array, seg_start: jax.Array) -> jax.Array:
 
     words: (M, L) u32; seg_start: (M,) bool marking segment heads.
     Row i receives the OR of earlier rows in its segment (0 at heads).
-    """
-    def combine(a, b):
-        fa, va = a
-        fb, vb = b
-        return fa | fb, jnp.where(fb[:, None], vb, va | vb)
 
-    flags = seg_start
-    incl_f, incl_v = jax.lax.associative_scan(combine, (flags, words))
-    del incl_f
+    Doubling scan (Hillis-Steele): after the step of stride s, row i holds
+    the OR over rows (i - 2s, i] of its segment, and ``head`` whether that
+    window reaches a segment head.  log2(M) shifted elementwise passes;
+    unlike ``lax.associative_scan`` it compiles quickly on TPU at large M.
+    """
+    m = words.shape[0]
+    acc, head = words, seg_start
+    s = 1
+    while s < m:
+        prev = jnp.concatenate([jnp.zeros_like(acc[:s]), acc[:-s]], axis=0)
+        prev_head = jnp.concatenate([jnp.zeros_like(head[:s]), head[:-s]])
+        acc = jnp.where(head[:, None], acc, acc | prev)
+        head = head | prev_head
+        s *= 2
     # exclusive = inclusive shifted down by one, zeroed at segment heads
-    shifted = jnp.concatenate([jnp.zeros_like(words[:1]), incl_v[:-1]], axis=0)
+    shifted = jnp.concatenate([jnp.zeros_like(words[:1]), acc[:-1]], axis=0)
     return jnp.where(seg_start[:, None], jnp.zeros_like(words), shifted)
 
 
@@ -87,7 +123,7 @@ def bulk_insert(tkeys, tvals, status, qblock, qkeys, qvals, qvalid,
         return hash_probe.insert(tkeys, tvals, status, qblock, qkeys,
                                  qvals, qvalid, mode)
 
-    nb, bsz, lk = tkeys.shape
+    nb, lk, bsz = tkeys.shape
     m = qblock.shape[0]
     lv = qvals.shape[1]
 
@@ -118,9 +154,9 @@ def bulk_insert(tkeys, tvals, status, qblock, qkeys, qvals, qvalid,
     leader_val = gval[group_id]   # value each leader should write
 
     # probe each leader's block
-    blk_keys = tkeys[sb % nb]                                   # (M, B, Lk)
+    blk_keys = tkeys[sb % nb]                                   # (M, Lk, B)
     blk_stat = status[sb % nb]                                  # (M, B)
-    match = (blk_keys == sk[:, None, :]).all(axis=2) & (bucket_state(blk_stat) == READY)
+    match = (blk_keys == sk[:, :, None]).all(axis=1) & (bucket_state(blk_stat) == READY)
     found = match.any(axis=1) & is_leader
     mslot = jnp.argmax(match, axis=1).astype(_I32)
 
@@ -153,7 +189,7 @@ def bulk_insert(tkeys, tvals, status, qblock, qkeys, qvals, qvalid,
     ok_leader = is_leader & (found | (new_leader & has_room))
 
     # value to store
-    old_val = tvals[sb_c, slot]
+    old_val = tvals[sb_c, :, slot]                              # (M, Lv)
     if mode == MODE_ADD:
         store_val = jnp.where(found[:, None], old_val + leader_val, leader_val)
     elif mode == MODE_KEEP:
@@ -162,8 +198,8 @@ def bulk_insert(tkeys, tvals, status, qblock, qkeys, qvals, qvalid,
         store_val = leader_val
 
     wb = jnp.where(ok_leader, sb_c, nb)    # drop sentinel
-    tkeys = tkeys.at[wb, slot].set(sk, mode="drop")
-    tvals = tvals.at[wb, slot].set(store_val, mode="drop")
+    tkeys = tkeys.at[wb, :, slot].set(sk, mode="drop")
+    tvals = tvals.at[wb, :, slot].set(store_val, mode="drop")
     old_st = status[sb_c, slot]
     status = status.at[wb, slot].set((old_st & ~STATE_MASK) | READY,
                                      mode="drop")
@@ -199,7 +235,7 @@ def bulk_find_arrivals(tkeys, tvals, status, seg, valid, impl: str = "auto"):
     if impl == "pallas":
         from repro.kernels import hash_probe
         return hash_probe.find_arrivals(tkeys, tvals, status, seg, valid)
-    lk = tkeys.shape[2]
+    lk = tkeys.shape[1]
     qblock = jnp.where(valid, seg[:, 0].astype(_I32), 0)
     return bulk_find(tkeys, tvals, status, qblock, seg[:, 1:1 + lk], valid,
                      impl=impl)
@@ -218,7 +254,7 @@ def bulk_insert_arrivals(tkeys, tvals, status, seg, valid,
         from repro.kernels import hash_probe
         return hash_probe.insert_arrivals(tkeys, tvals, status, seg, valid,
                                           mode)
-    lk = tkeys.shape[2]
+    lk = tkeys.shape[1]
     qblock = jnp.where(valid, seg[:, 0].astype(_I32), 0)
     return bulk_insert(tkeys, tvals, status, qblock, seg[:, 1:1 + lk],
                        seg[:, 1 + lk:], valid, mode, impl=impl)
@@ -394,11 +430,12 @@ def pack_rows(rows, bins, flow, offsets, valid, rnd: int, word_off,
     the flat ``(total,)`` u32 send buffer for retry round ``rnd``.  The
     jnp path is the declared fallback/oracle — :func:`ragged_slots`
     followed by ``object_container.scatter_rows`` (the two-pass XLA
-    lowering, DESIGN.md section 1.10); the Pallas path writes the wire
-    exactly once (``kernels/binning.pack_rows``).
+    lowering, DESIGN.md section 1.10); off the TPU the Pallas path
+    writes the wire exactly once (``kernels/binning.pack_rows``), and on
+    TPU it is the ``ragged_slots`` kernel plus that XLA scatter.
     """
     impl = _resolve(impl)
-    if impl == "pallas":
+    if _wire_kernel(impl):
         from repro.kernels import binning
         return binning.pack_rows(rows, bins, flow, offsets, valid, rnd,
                                  word_off, row_words, caps, rounds,
@@ -414,12 +451,11 @@ def place_rows(dst, slots, rows, impl: str = "auto"):
     """Scatter fixed-width (N, W) rows into ``dst`` at word ``slots``.
 
     Rows with ``slots[i] >= dst.size`` drop.  jnp path is
-    ``object_container.scatter_rows`` (the fallback/oracle); the Pallas
-    path folds the scatter into one kernel pass so analytic-slot writes
-    (dense replies, owner-side assembly) stay off XLA's scatter path.
+    ``object_container.scatter_rows`` (the fallback/oracle); off the
+    TPU the Pallas path folds the scatter into one kernel pass, and on
+    TPU it is that XLA scatter (:data:`XLA_WIRE_OPS`).
     """
-    impl = _resolve(impl)
-    if impl == "pallas":
+    if _wire_kernel(_resolve(impl)):
         from repro.kernels import binning
         return binning.place_rows(dst, slots, rows)
     from repro.core.object_container import scatter_rows

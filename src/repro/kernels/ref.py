@@ -6,9 +6,12 @@ test suite to validate both the vectorized jnp implementations in
 ``ops.py`` and the Pallas kernels (run in interpret mode on CPU).
 
 Hash-table layout (blocked open addressing, DESIGN.md section 2):
-  tkeys  (nb, B, Lk) u32   stored key lanes
-  tvals  (nb, B, Lv) u32   stored value lanes
+  tkeys  (nb, Lk, B) u32   stored key lanes, lane-major per block
+  tvals  (nb, Lv, B) u32   stored value lanes, lane-major per block
   status (nb, B)     u32   0=FREE, 1=RESERVED, 2=READY (paper's 2-bit state)
+
+A block's lane ``l`` is one B-wide row, so on TPU the table's minor axis
+is the B = 128 slots of a block and its HBM layout carries no padding.
 
 A key hashes to a block; probing is vectorized across the block's B slots.
 Cross-block overflow is handled by the container via bounded rehash
@@ -53,9 +56,9 @@ def hash_probe_insert_ref(tkeys, tvals, status, qblock, qkeys, qvals, qvalid,
         tk, tv, st, ok = carry
         b = qblock[i]
         key = qkeys[i]
-        blk_keys = tk[b]          # (B, Lk)
+        blk_keys = tk[b]          # (Lk, B)
         blk_stat = st[b]          # (B,)
-        match = (blk_keys == key[None, :]).all(axis=1) & (bucket_state(blk_stat) == READY)
+        match = (blk_keys == key[:, None]).all(axis=0) & (bucket_state(blk_stat) == READY)
         has_match = match.any()
         match_slot = jnp.argmax(match)
         free = bucket_state(blk_stat) == FREE
@@ -64,7 +67,7 @@ def hash_probe_insert_ref(tkeys, tvals, status, qblock, qkeys, qvals, qvalid,
         slot = jnp.where(has_match, match_slot, free_slot)
         can = qvalid[i] & (has_match | has_free)
 
-        old_val = tv[b, slot]
+        old_val = tv[b, :, slot]
         if mode == MODE_SET:
             new_val = qvals[i]
         elif mode == MODE_ADD:
@@ -72,8 +75,8 @@ def hash_probe_insert_ref(tkeys, tvals, status, qblock, qkeys, qvals, qvalid,
         else:  # MODE_KEEP: first writer wins
             new_val = jnp.where(has_match, old_val, qvals[i])
 
-        tk = tk.at[b, slot].set(jnp.where(can, key, tk[b, slot]))
-        tv = tv.at[b, slot].set(jnp.where(can, new_val, old_val))
+        tk = tk.at[b, :, slot].set(jnp.where(can, key, tk[b, :, slot]))
+        tv = tv.at[b, :, slot].set(jnp.where(can, new_val, old_val))
         old_st = st[b, slot]
         st = st.at[b, slot].set(jnp.where(can, (old_st & ~STATE_MASK) | READY, old_st))
         ok = ok.at[i].set(can)
@@ -87,12 +90,12 @@ def hash_probe_insert_ref(tkeys, tvals, status, qblock, qkeys, qvals, qvalid,
 
 def hash_probe_find_ref(tkeys, tvals, status, qblock, qkeys, qvalid):
     """Blocked find oracle: (found(M,), values(M, Lv))."""
-    blk_keys = tkeys[qblock]                  # (M, B, Lk)
+    blk_keys = tkeys[qblock]                  # (M, Lk, B)
     blk_stat = status[qblock]                 # (M, B)
-    match = (blk_keys == qkeys[:, None, :]).all(axis=2) & (bucket_state(blk_stat) == READY)
+    match = (blk_keys == qkeys[:, :, None]).all(axis=1) & (bucket_state(blk_stat) == READY)
     found = match.any(axis=1) & qvalid
     slot = jnp.argmax(match, axis=1)
-    vals = tvals[qblock, slot]
+    vals = tvals[qblock, :, slot]             # (M, Lv)
     return found, jnp.where(found[:, None], vals, jnp.zeros_like(vals))
 
 

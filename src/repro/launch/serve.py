@@ -19,12 +19,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models import lm
 
 
-def main(argv=None):
+def _parse(argv):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
     ap.add_argument("--reduced", action="store_true")
@@ -33,8 +34,19 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def run(argv=None) -> dict:
+    """Serve the requests; returns what came out and what it took.
+
+    Keys: ``outputs`` (request id -> generated token ids), ``vocab``,
+    ``logits_finite`` (every logits row of every step was finite),
+    ``n_decoded``, ``compile_s`` (prefill and decode programs, compiled
+    ahead of the first request) and ``run_s`` (serving the requests).
+    """
+    args = _parse(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
@@ -44,8 +56,15 @@ def main(argv=None):
     params = lm.init_params(cfg, jax.random.PRNGKey(args.seed))
 
     cache_len = args.prompt_len + args.gen
-    prefill = jax.jit(make_prefill_step(cfg, mesh, cache_len=cache_len))
-    decode = jax.jit(make_serve_step(cfg, mesh))
+    t0 = time.time()
+    tokens0 = jax.ShapeDtypeStruct((args.batch, args.prompt_len), jnp.int32)
+    prefill_jit = jax.jit(make_prefill_step(cfg, mesh, cache_len=cache_len))
+    prefill = prefill_jit.lower(params, {"tokens": tokens0}).compile()
+    cache0, _ = jax.eval_shape(prefill_jit, params, {"tokens": tokens0})
+    decode = jax.jit(make_serve_step(cfg, mesh)).lower(
+        params, cache0,
+        jax.ShapeDtypeStruct((args.batch, 1), jnp.int32)).compile()
+    compile_s = time.time() - t0
 
     # request queue
     prompts = rng.integers(0, cfg.vocab, (args.requests, args.prompt_len),
@@ -55,6 +74,7 @@ def main(argv=None):
 
     t_start = time.time()
     n_decoded = 0
+    finite = True
     while queue:
         active = queue[:args.batch]
         queue = queue[len(active):]
@@ -64,18 +84,27 @@ def main(argv=None):
                            np.int32)
             batch_prompts = np.concatenate([batch_prompts, pad])
         cache, logits = prefill(params, {"tokens": jnp.asarray(batch_prompts)})
+        finite &= bool(jnp.isfinite(logits).all())
         tok = jnp.argmax(logits, axis=-1)[:, None]
         for step in range(args.gen):
             for j, rid in enumerate(active):
                 outputs[rid].append(int(tok[j, 0]))
             logits, cache = decode(params, cache, tok.astype(jnp.int32))
+            finite &= bool(jnp.isfinite(logits).all())
             tok = jnp.argmax(logits, axis=-1)[:, None]
             n_decoded += len(active)
-    dt = time.time() - t_start
-    print(f"served {args.requests} requests, {n_decoded} tokens "
-          f"in {dt:.2f}s ({n_decoded / dt:.1f} tok/s)")
-    for i in range(min(3, args.requests)):
-        print(f"request {i}: {outputs[i][:10]}...")
+    return {"outputs": outputs, "vocab": cfg.vocab, "logits_finite": finite,
+            "n_decoded": n_decoded, "compile_s": compile_s,
+            "run_s": time.time() - t_start}
+
+
+def main(argv=None):
+    res = run(argv)
+    dt = res["run_s"]
+    print(f"served {len(res['outputs'])} requests, {res['n_decoded']} tokens "
+          f"in {dt:.2f}s ({res['n_decoded'] / dt:.1f} tok/s)")
+    for i in range(min(3, len(res["outputs"]))):
+        print(f"request {i}: {res['outputs'][i][:10]}...")
     return 0
 
 

@@ -27,6 +27,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager, latest_step
 from repro.configs import get_config, reduced
 from repro.data.tokens import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.launch.steps import (batch_shardings, init_state, make_train_step,
                                 train_shardings)
@@ -51,6 +52,7 @@ def main(argv=None):
                          "wire, overlap the always-on paths, then finish "
                          "(DESIGN.md section 1.9)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

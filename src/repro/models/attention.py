@@ -19,7 +19,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+
 
 _F32 = jnp.float32
 _NEG = -1e30
@@ -390,14 +390,13 @@ def mla_absorbed_decode_cp(params, cfg, q_nope, q_rope, new_c, new_kr,
     for a in d:
         n_data *= mesh.shape[a]
     lead = d if bdim % n_data == 0 else None
-    ctx, ckv2, kr2 = shard_map(
+    ctx, ckv2, kr2 = jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(lead, None, None), P(lead, None, None),
                   P(lead, None), P(lead, None),
                   P(lead, axes.model, None), P(lead, axes.model, None)),
         out_specs=(P(lead, None, None),
                    P(lead, axes.model, None), P(lead, axes.model, None)),
-        check_vma=False,
     )(q_lat, qr, new_c, new_kr, cache["c_kv"], cache["k_rope"])
     out = jnp.einsum("bhr,rhv->bhv", ctx, w_uv.astype(_F32))
     return (out.reshape(b, 1, h * vdim).astype(q_nope.dtype),

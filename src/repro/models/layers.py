@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.sharding import Axes, shard
-from repro.compat import shard_map
 
 
 def rms_norm(x, gamma, eps: float = 1e-5):
@@ -93,8 +92,8 @@ def embed_lookup(table, tokens, mesh: Mesh, axes: Axes):
 
     in_specs = (P(axes.model, None), P(lead, None))
     out_specs = P(lead, None, None)
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)(table, tokens)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)(table, tokens)
 
 
 def embed_lookup_dense(table, tokens):

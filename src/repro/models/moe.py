@@ -37,7 +37,7 @@ from repro.core.backend import SpmdBackend
 from repro.core.exchange import ExchangePlan
 from repro.core.transport import make_transport
 from repro.models.sharding import Axes
-from repro.compat import shard_map
+
 
 _F32 = jnp.float32
 _U32 = jnp.uint32
@@ -377,13 +377,17 @@ def moe_apply(params, x, cfg, mesh: Mesh, axes: Axes):
     # under split-phase dispatch the shared/dense trees ride into the
     # shard_map (replicated) so the window can compute them on xl rows
     extra_args = tuple(params[kk] for kk in extra_keys) if async_ else ()
-    y, load, drops = shard_map(
+    y, load, drops = jax.shard_map(
         dispatch, mesh=mesh,
         in_specs=(in_x, in_i, in_i,
                   espec(None, None), espec(None, None), espec(None, None))
                  + tuple(P() for _ in extra_args),
         out_specs=(in_x, P(din, None), P(din)),
-        check_vma=False,   # replication over 'model' holds by construction
+        # every model rank dispatches the same tokens and gets the same
+        # replies back through the exchange's all_to_all, so the outputs
+        # are replicated over 'model' by construction; vma typing cannot
+        # see through the all_to_all, so the check stays off here
+        check_vma=False,
     )(x, top_idx.astype(_I32), top_w,
       params["experts"]["w_gate"], params["experts"]["w_in"],
       params["experts"]["w_out"], *extra_args)

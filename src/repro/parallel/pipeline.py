@@ -20,7 +20,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 
 
 def gpipe(stage_fn: Callable, stacked_params, x_microbatches, mesh: Mesh,
@@ -50,17 +49,17 @@ def gpipe(stage_fn: Callable, stacked_params, x_microbatches, mesh: Mesh,
             nxt = jax.lax.ppermute(y, axis, perm)
             return nxt, y
 
-        _, ys = jax.lax.scan(step, jnp.zeros_like(x_all[0]),
-                             jnp.arange(ticks))
+        # the ring buffer varies over the stage axis from the first tick
+        buf0 = jax.lax.pcast(jnp.zeros_like(x_all[0]), (axis,), to="varying")
+        _, ys = jax.lax.scan(step, buf0, jnp.arange(ticks))
         # the final stage emits microbatch i at tick i + (s-1)
         out = jax.lax.dynamic_slice_in_dim(ys, s - 1, m, axis=0)
         out = jnp.where(sid == s - 1, out, 0)
         return jax.lax.psum(out, axis)      # broadcast the result
 
     nd = x_microbatches.ndim
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P(*([None] * nd))),
         out_specs=P(*([None] * nd)),
-        check_vma=False,
     )(stacked_params, x_microbatches)

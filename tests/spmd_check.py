@@ -18,7 +18,7 @@ from jax.sharding import PartitionSpec as P
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 
 from repro.core import ConProm, Promise, get_backend, route
 from repro.containers import bloom as bl
@@ -52,7 +52,7 @@ def main():
     vals = keys * 7 + 1
     queries = jnp.concatenate([keys[:PROCS * NLOC // 2],
                                keys[:PROCS * NLOC // 2] + (1 << 21)])
-    f = jax.jit(shard_map(build_and_query, mesh=mesh,
+    f = jax.jit(jax.shard_map(build_and_query, mesh=mesh,
                               in_specs=(P("bcl"),) * 3,
                               out_specs=(P("bcl"),) * 3))
     ok, v, found = f(keys, vals, queries)
@@ -74,7 +74,7 @@ def main():
     vals2 = jnp.asarray(np.random.randint(0, 1 << 20, PROCS * 100),
                         jnp.uint32)
     dest2 = (vals2 // ((1 << 20) // 8)).astype(jnp.int32).clip(0, 7)
-    g = jax.jit(shard_map(isx, mesh=mesh, in_specs=(P("bcl"),) * 2,
+    g = jax.jit(jax.shard_map(isx, mesh=mesh, in_specs=(P("bcl"),) * 2,
                               out_specs=(P("bcl"),) * 3))
     rows, got, dropped = g(vals2, dest2)
     rec = np.asarray(rows)[np.asarray(got)]
@@ -111,7 +111,7 @@ def main():
             return (v, f, ok, out, got, pushed[None], dropped[None],
                     st.status)
 
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 7,
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 7,
                                  out_specs=(P("bcl"),) * 8))
 
     rngf = np.random.default_rng(42)
@@ -157,7 +157,7 @@ def main():
                     v1.payload, v1.valid, v3.payload, v3.valid,
                     v1.dropped[None], v3.dropped[None])
 
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 4,
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 4,
                                  out_specs=(P("bcl"),) * 10))
 
     rr = np.random.default_rng(23)
@@ -194,7 +194,7 @@ def main():
                     v1.payload, v1.valid, v3.payload, v3.valid,
                     v1.dropped[None], v3.dropped[None])
 
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 4,
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 4,
                                  out_specs=(P("bcl"),) * 10))
 
     for tag, tr_a in (("plan.async_equals_sync_8rank", None),
@@ -224,7 +224,7 @@ def main():
             rows, got = q.local_drain(spec, st)
             return rows, got, dropped[None]
 
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 2,
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 2,
                                  out_specs=(P("bcl"),) * 3))(zvals, zdest)
 
     _, _, zdrop1 = zpush(1)
@@ -279,7 +279,7 @@ def main():
                     bst.words, o3[0], o3[1], v3.payload, v3.valid,
                     v3.dropped[None])
 
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 9,
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 9,
                                  out_specs=(P("bcl"),) * 21))
 
     tb_args = fi_args + (rg_args[1], rg_args[3])
@@ -338,7 +338,7 @@ def main():
                     out, got, st.tkeys, st.tvals, st.status,
                     o3[0], o3[1], v3.payload, v3.valid, v3.dropped[None])
 
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 9,
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 9,
                                  out_specs=(P("bcl"),) * 18))
 
     for tag, tr_w, split in (("dense_sync", None, False),
@@ -369,7 +369,7 @@ def main():
                         transport=ftr, integrity=True)
             return res.payload, res.valid, res.lost[None], res.dropped[None]
 
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 2,
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 2,
                                  out_specs=(P("bcl"),) * 4))
 
     wf_rng = np.random.default_rng(9)
@@ -405,7 +405,7 @@ def main():
             return res.payload, res.valid, res.dropped[None]
 
         with _costs.recording() as log:
-            out = jax.jit(shard_map(body, mesh=mesh,
+            out = jax.jit(jax.shard_map(body, mesh=mesh,
                                     in_specs=(P("bcl"),),
                                     out_specs=(P("bcl"),) * 3))(sp_pay)
         return out, log
@@ -440,7 +440,7 @@ def main():
         return already
 
     dup = jnp.full((PROCS * 16,), 777, jnp.uint32)
-    fb = jax.jit(shard_map(bloomdup, mesh=mesh, in_specs=(P("bcl"),),
+    fb = jax.jit(jax.shard_map(bloomdup, mesh=mesh, in_specs=(P("bcl"),),
                                out_specs=P("bcl")))
     already = np.asarray(fb(dup))
     check("bloom.dup_atomicity", int((~already).sum()) == 1)
@@ -548,7 +548,7 @@ def main():
             got.sum()[None]
 
     keys8 = jnp.asarray(np.random.randint(0, 1 << 20, 8 * 1024), jnp.uint32)
-    fw = jax.jit(shard_map(isx_weak, mesh=mesh, in_specs=(P("bcl"),),
+    fw = jax.jit(jax.shard_map(isx_weak, mesh=mesh, in_specs=(P("bcl"),),
                                out_specs=(P("bcl"), P("bcl"))))
     srted, counts = fw(keys8)
     merged = np.asarray(srted).reshape(8, -1)
@@ -620,7 +620,7 @@ def main():
                 qex["data"], qex["head"], qex["tail"], qex["tail_ready"],
                 qex["head_ready"])
 
-    a = jax.jit(shard_map(phase_a, mesh=mesh, in_specs=(P("bcl"),) * 4,
+    a = jax.jit(jax.shard_map(phase_a, mesh=mesh, in_specs=(P("bcl"),) * 4,
                           out_specs=(P("bcl"),) * 10))(b1k, b1v, qv1, qd1)
     check("chaos.phase_a_clean", bool(np.asarray(a[0]).all())
           and int(np.asarray(a[1]).sum()) == 0)
@@ -650,7 +650,7 @@ def main():
                             dead_ranks=(KILLED,), integrity=True)
         return ok2
 
-    ok2 = jax.jit(shard_map(phase_b, mesh=mesh, in_specs=(P("bcl"),) * 5,
+    ok2 = jax.jit(jax.shard_map(phase_b, mesh=mesh, in_specs=(P("bcl"),) * 5,
                             out_specs=P("bcl")))(a[2], a[3], a[4], b2k, b2v)
     g0 = np.asarray(hash_lanes(b2k[:, None], seed=1)) % 512
     owner0 = g0 // 64                       # 512 blocks, 64 per rank
@@ -717,7 +717,7 @@ def main():
         return (ex["tkeys"], ex["tvals"], ex["status"],
                 qex["data"], qex["head"], qex["tail"])
 
-    rec = jax.jit(shard_map(recover, mesh=mesh, in_specs=(P("bcl"),) * 12,
+    rec = jax.jit(jax.shard_map(recover, mesh=mesh, in_specs=(P("bcl"),) * 12,
                             out_specs=(P("bcl"),) * 8))(
         jnp.asarray(got_ck["hm"]["tkeys"]), jnp.asarray(got_ck["hm"]["tvals"]),
         jnp.asarray(got_ck["hm"]["status"]), jnp.asarray(got_ck["q"]["data"]),
@@ -725,7 +725,7 @@ def main():
         jnp.asarray(got_ck["q"]["tail_ready"]),
         jnp.asarray(got_ck["q"]["head_ready"]),
         b2k, b2v, qv2, qd2)
-    ref = jax.jit(shard_map(reference, mesh=mesh, in_specs=(P("bcl"),) * 8,
+    ref = jax.jit(jax.shard_map(reference, mesh=mesh, in_specs=(P("bcl"),) * 8,
                             out_specs=(P("bcl"),) * 6))(
         b1k, b1v, b2k, b2v, qv1, qd1, qv2, qd2)
     check("chaos.recovery_replay_clean", bool(np.asarray(rec[0]).all())
@@ -750,7 +750,7 @@ def main():
                     transport=ltr, integrity=True)
         return (res.valid.sum()[None], res.lost[None], res.dropped[None])
 
-    arr, lost, drp = jax.jit(shard_map(
+    arr, lost, drp = jax.jit(jax.shard_map(
         corrupt_lose, mesh=mesh, in_specs=(P("bcl"),) * 2,
         out_specs=(P("bcl"),) * 3))(lv[:, None], ld)
     n_lost = int(np.asarray(lost)[0])
@@ -774,7 +774,7 @@ def main():
         rows, got = q.local_drain(qspec, qst)
         return carry.sum()[None], carry2.sum()[None], rows, got
 
-    c1, c2, hrows, hgot = jax.jit(shard_map(
+    c1, c2, hrows, hgot = jax.jit(jax.shard_map(
         corrupt_heal, mesh=mesh, in_specs=(P("bcl"),) * 2,
         out_specs=(P("bcl"),) * 4))(lv, ld)
     healed = np.asarray(hrows)[np.asarray(hgot)]
@@ -800,7 +800,7 @@ def main():
         res = c.view(h)
         return (res.valid.sum()[None], res.lost[None], res.dropped[None])
 
-    arr_a, lost_a, drp_a = jax.jit(shard_map(
+    arr_a, lost_a, drp_a = jax.jit(jax.shard_map(
         corrupt_lose_async, mesh=mesh, in_specs=(P("bcl"),) * 2,
         out_specs=(P("bcl"),) * 3))(lv[:, None], ld)
     check("chaos.async_corrupt_lost_accounted",
@@ -839,7 +839,7 @@ def main():
             return (carry.sum()[None], carry2.sum()[None], rows, got,
                     out1, got1, out2, got2)
 
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 2,
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("bcl"),) * 2,
                                  out_specs=(P("bcl"),) * 8))(lv, ld)
 
     hp_sync = heal_pair(False)

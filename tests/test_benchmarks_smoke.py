@@ -284,3 +284,20 @@ def test_smoke_costs_pin_round_reduction():
         st, keys)
     assert c2.collectives == 2 and c2.rounds == 2
     assert c_seq.collectives == 4 and c_seq.rounds == 4
+
+
+def test_run_exits_nonzero_after_error_row(monkeypatch, capsys):
+    """A module that raises prints its ERROR row and fails the run."""
+    from benchmarks import micro_queue, run
+    import repro.launch.compile_cache as cc
+
+    def boom(smoke=False):
+        raise RuntimeError("boom")
+
+    monkeypatch.setattr(micro_queue, "run", boom)
+    monkeypatch.setattr(cc, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(sys, "argv", ["run.py", "--smoke", "micro_queue"])
+    with pytest.raises(SystemExit) as exc:
+        run.main()
+    assert exc.value.code not in (None, 0)
+    assert "micro_queue,ERROR" in capsys.readouterr().out

@@ -12,8 +12,8 @@ from repro.kernels import flash_attention as fa
 
 
 def _mk_table(nb, B, lk, lv):
-    return (jnp.zeros((nb, B, lk), jnp.uint32),
-            jnp.zeros((nb, B, lv), jnp.uint32),
+    return (jnp.zeros((nb, lk, B), jnp.uint32),
+            jnp.zeros((nb, lv, B), jnp.uint32),
             jnp.zeros((nb, B), jnp.uint32))
 
 

@@ -71,8 +71,8 @@ def test_bulk_insert_impls_agree(pairs, mode):
     """jnp and pallas implementations match the sequential oracle on
     arbitrary (dup-heavy) batches."""
     nb, B = 4, 8
-    tk = jnp.zeros((nb, B, 1), jnp.uint32)
-    tv = jnp.zeros((nb, B, 1), jnp.uint32)
+    tk = jnp.zeros((nb, 1, B), jnp.uint32)
+    tv = jnp.zeros((nb, 1, B), jnp.uint32)
     stt = jnp.zeros((nb, B), jnp.uint32)
     qk = jnp.asarray([[k] for k, _ in pairs], jnp.uint32)
     qv = jnp.asarray([[v] for _, v in pairs], jnp.uint32)

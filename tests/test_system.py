@@ -1,5 +1,7 @@
 """End-to-end behaviour tests for the whole system."""
 
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -144,3 +146,27 @@ def test_tiny_training_learns(mesh11, tmp_path):
         params, opt, m = step_fn(params, opt, batch)
         losses.append(float(m["loss"]))
     assert losses[-1] < losses[0] * 0.8, losses
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_directory(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; unset, the
+    cache is the checkout's fixed .jax_cache, whatever the cwd."""
+    from repro.launch.compile_cache import CHECKOUT_CACHE, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.chdir(tmp_path)
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env_dir))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = enable_compile_cache()
+        if env_dir:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert got == str(CHECKOUT_CACHE)
+            assert CHECKOUT_CACHE.parent == pathlib.Path(__file__).resolve().parents[1]
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
