@@ -30,9 +30,8 @@ report the loss, the heal, and the dead-rank mask.
 The ``--wire {scatter,fused}`` arm re-runs every variant with the
 send-buffer construction pinned (DESIGN.md section 1.10): ``scatter``
 forces the two-pass scatter_rows fallback, ``fused`` the one-kernel
-Pallas pack; rows gain the suffix and the hbm_passes column reports the
-traced call's standalone scatter-op count (strictly fewer when fused,
-identical bytes/collectives).
+Pallas pack; rows gain the suffix (identical bytes/collectives either
+way).
 
 Reported as microseconds per operation (amortized over the batch) plus
 the collective/bytes/rounds observables and rounds_per_op, so the
@@ -48,8 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import ShapeDtypeStruct as SDS
 
-from benchmarks.util import (count_hbm_passes, emit, resolve_transport,
-                             resolve_wire, time_fn, trace_costs)
+from benchmarks.util import (emit, resolve_transport, resolve_wire,
+                             time_fn, trace_costs)
 from repro.core import ConProm, Promise, get_backend
 from repro.containers import hashmap as hm
 from repro.containers import hashmap_buffer as hb
@@ -73,7 +72,6 @@ def run(smoke: bool = False, fused: bool = False, skew: str = "none",
     vals = keys * 3 + 1
     results = {}
     obs = {}
-    passes = {}
 
     def fresh():
         return hm.hashmap_create(bk, table, SDS((), jnp.uint32),
@@ -82,7 +80,6 @@ def run(smoke: bool = False, fused: bool = False, skew: str = "none",
 
     def bench(tag, fn, *args):
         obs[tag] = trace_costs(fn, *args)
-        passes[tag] = count_hbm_passes(fn, *args)
         results[tag] = time_fn(fn, *args) / n_ops * 1e6
 
     # --- insert (fully atomic), issued in WAVES batches ---
@@ -287,22 +284,17 @@ def run(smoke: bool = False, fused: bool = False, skew: str = "none",
              unreachable=int(flog.total().unreachable))
 
     emit("hashmap_insert" + sfx, results["hashmap_insert"], "2A+W",
-         cost=obs["hashmap_insert"], n_ops=n_ops,
-         hbm_passes=passes["hashmap_insert"])
+         cost=obs["hashmap_insert"], n_ops=n_ops)
     emit("hashmap_insert_buffer" + sfx, results["hashmap_insert_buffer"],
          f"speedup={results['hashmap_insert'] / results['hashmap_insert_buffer']:.2f}x",
-         cost=obs["hashmap_insert_buffer"], n_ops=n_ops,
-         hbm_passes=passes["hashmap_insert_buffer"])
+         cost=obs["hashmap_insert_buffer"], n_ops=n_ops)
     emit("hashmap_find_atomic" + sfx, results["hashmap_find_atomic"], "2A+R",
-         cost=obs["hashmap_find_atomic"], n_ops=n_ops,
-         hbm_passes=passes["hashmap_find_atomic"])
+         cost=obs["hashmap_find_atomic"], n_ops=n_ops)
     emit("hashmap_find" + sfx, results["hashmap_find"],
          f"speedup={results['hashmap_find_atomic'] / results['hashmap_find']:.2f}x",
-         cost=obs["hashmap_find"], n_ops=n_ops,
-         hbm_passes=passes["hashmap_find"])
+         cost=obs["hashmap_find"], n_ops=n_ops)
     emit("hashmap_find_2attempt" + sfx, results["hashmap_find_2attempt"],
-         "2 rounds/wave", cost=obs["hashmap_find_2attempt"], n_ops=n_ops,
-         hbm_passes=passes["hashmap_find_2attempt"])
+         "2 rounds/wave", cost=obs["hashmap_find_2attempt"], n_ops=n_ops)
     if fused:
         emit("hashmap_find_insert_fused" + sfx, results["hashmap_find_insert_fused"],
              "2 collectives/round-trip",

@@ -26,8 +26,7 @@ The ``--async`` arm adds the split-phase pair (DESIGN.md section 1.9):
 
 The ``--wire {scatter,fused}`` arm re-runs every variant with the
 send-buffer construction pinned (DESIGN.md section 1.10): rows gain the
-``_scatter`` / ``_fused`` suffix and the hbm_passes column reports the
-traced call's standalone scatter-op count.
+``_scatter`` / ``_fused`` suffix.
 
 The ``--faults`` arm (DESIGN.md section 1.8) pushes through a
 FaultInjectingTransport with a seeded corrupt spec under the integrity
@@ -47,8 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import ShapeDtypeStruct as SDS
 
-from benchmarks.util import (count_hbm_passes, emit, resolve_transport,
-                             resolve_wire, time_fn, trace_costs)
+from benchmarks.util import (emit, resolve_transport, resolve_wire,
+                             time_fn, trace_costs)
 from repro.core import ConProm, Promise, get_backend
 from repro.containers import queue as q
 
@@ -70,7 +69,6 @@ def run(smoke: bool = False, fused: bool = False, skew: str = "none",
     wave = n_ops // WAVES
     results = {}
     obs = {}
-    passes = {}
 
     def bench_push(circular, promise, tag):
         spec, st0 = q.queue_create(bk, n_ops * 2, SDS((), jnp.uint32),
@@ -87,7 +85,6 @@ def run(smoke: bool = False, fused: bool = False, skew: str = "none",
             return st
 
         obs[tag] = trace_costs(pushes, st0, vals, dest)
-        passes[tag] = count_hbm_passes(pushes, st0, vals, dest)
         t = time_fn(pushes, st0, vals, dest)
         results[tag] = t / n_ops * 1e6
         return spec, pushes
@@ -111,7 +108,6 @@ def run(smoke: bool = False, fused: bool = False, skew: str = "none",
             return st, outs
 
         obs[tag] = trace_costs(pops, st0)
-        passes[tag] = count_hbm_passes(pops, st0)
         t = time_fn(pops, st0)
         results[tag] = t / n_ops * 1e6
 
@@ -130,7 +126,6 @@ def run(smoke: bool = False, fused: bool = False, skew: str = "none",
         return st, out
 
     obs["fq_local_pop"] = trace_costs(local_pops, st0)
-    passes["fq_local_pop"] = count_hbm_passes(local_pops, st0)
     results["fq_local_pop"] = time_fn(local_pops, st0) / n_ops * 1e6
 
     # --- fused arm: push+pop sharing one plan vs the FINE oracle ---
@@ -264,7 +259,7 @@ def run(smoke: bool = False, fused: bool = False, skew: str = "none",
               "cq_pop_pushpop", "cq_pop_pop", "fq_pop", "fq_local_pop"):
         emit(k + sfx, results[k],
              "2A" if "pushpop" in k else ("A" if k.startswith("fq") else "2A"),
-             cost=obs[k], n_ops=n_ops, hbm_passes=passes[k])
+             cost=obs[k], n_ops=n_ops)
     if fused:
         emit("cq_push_pop_fused" + sfx, results["cq_push_pop_fused"],
              "2 collectives/wave", cost=obs["cq_push_pop_fused"],

@@ -45,9 +45,7 @@ row (the charge-once-at-wait attribution rule).
 (DESIGN.md section 1.10) on the modules that have wire arms: ``scatter``
 forces the documented scatter_rows fallback (impl="jnp"), ``fused`` the
 one-kernel Pallas pack (impl="pallas"); rows are suffixed ``_scatter`` /
-``_fused`` and the hbm_passes column reports the traced call's
-standalone scatter-op count — fewer on the fused path, same bytes and
-collectives everywhere.
+``_fused``, with the same bytes and collectives on both paths.
 """
 
 from __future__ import annotations

@@ -41,28 +41,9 @@ def trace_costs(fn, *args, **kw):
 #: tests/test_benchmarks_smoke.py).  ``hops`` counts physical exchange
 #: stages (1 per dense launch, 2 per hierarchical launch) so the
 #: ``--transport`` arms' extra stage shows up next to wall time.
-#: ``hbm_passes`` counts standalone XLA scatter-family ops in the traced
-#: call (launch/jaxpr_stats.op_counts) — the ``--wire`` arms' structural
-#: observable: the fused Pallas wire path writes each send buffer once
-#: in-kernel, so its rows report strictly fewer passes than the
-#: scatter_rows fallback (DESIGN.md section 1.10).
 HEADER = ("name,us_per_call,collectives,bytes_moved,rounds,"
           "rounds_per_op,retry_rounds,dropped,hops,"
-          "lost_bytes,recovered,unreachable,overlap_launches,"
-          "hbm_passes,derived")
-
-
-def count_hbm_passes(fn, *args) -> int:
-    """Standalone scatter-family op count of ONE traced call of ``fn``.
-
-    Pallas kernel bodies are opaque (their in-kernel stores are vector
-    writes, not HBM scatter passes), so this is exactly the number of
-    XLA gather/scatter wire passes the call pays — the ``hbm_passes``
-    CSV column.
-    """
-    from repro.launch import jaxpr_stats
-    counts = jaxpr_stats.op_counts(fn, *args)
-    return sum(v for k, v in counts.items() if k.startswith("scatter"))
+          "lost_bytes,recovered,unreachable,overlap_launches,derived")
 
 
 def resolve_wire(name: str):
@@ -160,7 +141,7 @@ def emit(name: str, us_per_call: float, derived: str = "",
          cost=None, n_ops: int | None = None,
          retry_rounds: int | None = None, dropped: int | None = None,
          lost_bytes: int | None = None, recovered: int | None = None,
-         unreachable: int | None = None, hbm_passes: int | None = None):
+         unreachable: int | None = None):
     """CSV row following :data:`HEADER`.
 
     ``rounds_per_op`` (rounds amortized over ``n_ops`` data-structure
@@ -184,10 +165,9 @@ def emit(name: str, us_per_call: float, derived: str = "",
     lb = "" if lost_bytes is None else str(lost_bytes)
     rc = "" if recovered is None else str(recovered)
     un = "" if unreachable is None else str(unreachable)
-    hp = "" if hbm_passes is None else str(hbm_passes)
     if cost is None:
         print(f"{name},{us_per_call:.2f},,,,,{rr},{dr},,"
-              f"{lb},{rc},{un},,{hp},{derived}")
+              f"{lb},{rc},{un},,{derived}")
         return
     if lost_bytes is None:
         lb = str(cost.lost_bytes)
@@ -196,5 +176,5 @@ def emit(name: str, us_per_call: float, derived: str = "",
     rpo = f"{cost.rounds / n_ops:.6f}" if n_ops else ""
     print(f"{name},{us_per_call:.2f},{cost.collectives},"
           f"{cost.bytes_moved},{cost.rounds},{rpo},{rr},{dr},"
-          f"{cost.hops},{lb},{rc},{un},{cost.overlap_launches},{hp},"
+          f"{cost.hops},{lb},{rc},{un},{cost.overlap_launches},"
           f"{derived}")

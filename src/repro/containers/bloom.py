@@ -102,29 +102,32 @@ def insert(backend: Backend, spec: BloomSpec, state: BloomState,
     set before item i's own insertion — first-inserter-wins across the
     whole machine and within the batch (paper's atomicity invariant).
     """
-    n, c, h, res, rb, rw = _route_words(
-        backend, spec, items, valid, capacity, "bloom.insert",
-        max_rounds=max_rounds, transport=transport)
-    words, already = kops.bloom_insert(state.words, rb, rw, res.valid,
-                                       impl=spec.impl)
-    c.set_reply(h, already.astype(_U32))
-    back, _ = c.finish(backend)[h]
-    costs.record("bloom.insert", costs.Cost(A=1))
-    return BloomState(words), back[:, 0] == 1
+    with costs.scope("bloom.insert"):
+        n, c, h, res, rb, rw = _route_words(
+            backend, spec, items, valid, capacity, "bloom.insert",
+            max_rounds=max_rounds, transport=transport)
+        words, already = kops.bloom_insert(state.words, rb, rw, res.valid,
+                                           impl=spec.impl)
+        c.set_reply(h, already.astype(_U32))
+        back, _ = c.finish(backend)[h]
+        costs.record("bloom.insert", costs.Cost(A=1))
+        return BloomState(words), back[:, 0] == 1
 
 
 def find(backend: Backend, spec: BloomSpec, state: BloomState,
          items, capacity: int, valid: jax.Array | None = None,
          max_rounds: int = 1, transport=None):
     """Membership query; returns present(N,). Cost R."""
-    n, c, h, res, rb, rw = _route_words(
-        backend, spec, items, valid, capacity, "bloom.find",
-        max_rounds=max_rounds, transport=transport)
-    present = kops.bloom_find(state.words, rb, rw, res.valid, impl=spec.impl)
-    c.set_reply(h, present.astype(_U32))
-    back, _ = c.finish(backend)[h]
-    costs.record("bloom.find", costs.Cost(R=n))
-    return back[:, 0] == 1
+    with costs.scope("bloom.find"):
+        n, c, h, res, rb, rw = _route_words(
+            backend, spec, items, valid, capacity, "bloom.find",
+            max_rounds=max_rounds, transport=transport)
+        present = kops.bloom_find(state.words, rb, rw, res.valid,
+                                  impl=spec.impl)
+        c.set_reply(h, present.astype(_U32))
+        back, _ = c.finish(backend)[h]
+        costs.record("bloom.find", costs.Cost(R=n))
+        return back[:, 0] == 1
 
 
 def insert_find(backend: Backend, spec: BloomSpec, state: BloomState,
@@ -149,34 +152,46 @@ def insert_find(backend: Backend, spec: BloomSpec, state: BloomState,
     and instead returns a :class:`~repro.core.PendingResult` whose
     ``finish()`` yields the same triple.
     """
-    validate(promise)
-    if fine_grained(promise):
-        def _fine():
-            st, already = insert(backend, spec, state, ins_items,
-                                 capacity_ins, valid=ins_valid,
-                                 max_rounds=max_rounds, transport=transport)
-            present = find(backend, spec, st, find_items, capacity_find,
-                           valid=find_valid, max_rounds=max_rounds,
-                           transport=transport)
-            return st, already, present
-        # split-phase FINE stays the sequential oracle: run eagerly
-        return PendingResult(lambda s=_fine(): s) if async_ else _fine()
+    with costs.scope("bloom.insert_find"):
+        validate(promise)
+        if fine_grained(promise):
+            def _fine():
+                st, already = insert(backend, spec, state, ins_items,
+                                     capacity_ins, valid=ins_valid,
+                                     max_rounds=max_rounds,
+                                     transport=transport)
+                present = find(backend, spec, st, find_items, capacity_find,
+                               valid=find_valid, max_rounds=max_rounds,
+                               transport=transport)
+                return st, already, present
+            # split-phase FINE stays the sequential oracle: run eagerly
+            return PendingResult(lambda s=_fine(): s) if async_ else _fine()
 
-    ni, body_i, owner_i, ins_valid = _words_of(spec, ins_items, ins_valid)
-    nf, body_f, owner_f, find_valid = _words_of(spec, find_items, find_valid)
-    plan = ExchangePlan(name="bloom.insert_find")
-    hi = plan.add(body_i, owner_i, capacity_ins, reply_lanes=1,
-                  valid=ins_valid, op_name="bloom.insert")
-    hf = plan.add(body_f, owner_f, capacity_find, reply_lanes=1,
-                  valid=find_valid, op_name="bloom.find")
-    if async_:
-        pend = plan.commit_async(backend, impl=spec.impl,
-                                 max_rounds=max_rounds, transport=transport)
-        return PendingResult(lambda: _insert_find_complete(
-            backend, spec, state, pend.finish(backend), hi, hf, nf))
-    c = plan.commit(backend, impl=spec.impl, max_rounds=max_rounds,
-                    transport=transport)
-    return _insert_find_complete(backend, spec, state, c, hi, hf, nf)
+        ni, body_i, owner_i, ins_valid = _words_of(spec, ins_items,
+                                                   ins_valid)
+        nf, body_f, owner_f, find_valid = _words_of(spec, find_items,
+                                                    find_valid)
+        plan = ExchangePlan(name="bloom.insert_find")
+        hi = plan.add(body_i, owner_i, capacity_ins, reply_lanes=1,
+                      valid=ins_valid, op_name="bloom.insert")
+        hf = plan.add(body_f, owner_f, capacity_find, reply_lanes=1,
+                      valid=find_valid, op_name="bloom.find")
+        if async_:
+            pend = plan.commit_async(backend, impl=spec.impl,
+                                     max_rounds=max_rounds,
+                                     transport=transport)
+
+            def complete():
+                # the completion tail is traced at finish(), outside the
+                # scope above: it takes the op's name again
+                with costs.scope("bloom.insert_find"):
+                    return _insert_find_complete(
+                        backend, spec, state, pend.finish(backend), hi, hf,
+                        nf)
+            return PendingResult(complete)
+        c = plan.commit(backend, impl=spec.impl, max_rounds=max_rounds,
+                        transport=transport)
+        return _insert_find_complete(backend, spec, state, c, hi, hf, nf)
 
 
 def _insert_find_complete(backend, spec, state, c, hi, hf, nf):

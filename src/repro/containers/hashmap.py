@@ -75,17 +75,18 @@ def hashmap_create(backend: Backend, capacity: int, key_spec, val_spec,
                    block_size: int = 128,
                    impl: str = "auto") -> tuple[HashMapSpec, HashMapState]:
     """Collective constructor (paper 5.1.1): fixed size, fixed K/V types."""
-    kp, vp = packer_for(key_spec), packer_for(val_spec)
-    nprocs = backend.nprocs()
-    nb_global = max(1, -(-capacity // block_size))
-    nb_global = -(-nb_global // nprocs) * nprocs       # round up to P
-    nb_local = nb_global // nprocs
-    spec = HashMapSpec(nb_global, nb_local, block_size, kp, vp, impl)
-    state = HashMapState(
-        jnp.zeros((nb_local, kp.lanes, block_size), _U32),
-        jnp.zeros((nb_local, vp.lanes, block_size), _U32),
-        jnp.zeros((nb_local, block_size), _U32))
-    return spec, state
+    with costs.scope("hashmap.create"):
+        kp, vp = packer_for(key_spec), packer_for(val_spec)
+        nprocs = backend.nprocs()
+        nb_global = max(1, -(-capacity // block_size))
+        nb_global = -(-nb_global // nprocs) * nprocs       # round up to P
+        nb_local = nb_global // nprocs
+        spec = HashMapSpec(nb_global, nb_local, block_size, kp, vp, impl)
+        state = HashMapState(
+            jnp.zeros((nb_local, kp.lanes, block_size), _U32),
+            jnp.zeros((nb_local, vp.lanes, block_size), _U32),
+            jnp.zeros((nb_local, block_size), _U32))
+        return spec, state
 
 
 def _block_of(spec: HashMapSpec, key_lanes: jax.Array,
@@ -131,67 +132,69 @@ def insert(backend: Backend, spec: HashMapSpec, state: HashMapState,
     checksum-failed arrival never acks, so the requester sees it as
     unsuccessful and the attempt loop re-sends it.
     """
-    validate(promise)
-    klanes = spec.key_packer.pack(keys)
-    vlanes = spec.val_packer.pack(vals)
-    n = klanes.shape[0]
-    if valid is None:
-        valid = jnp.ones((n,), bool)
+    with costs.scope("hashmap.insert"):
+        validate(promise)
+        klanes = spec.key_packer.pack(keys)
+        vlanes = spec.val_packer.pack(vals)
+        n = klanes.shape[0]
+        if valid is None:
+            valid = jnp.ones((n,), bool)
 
-    if local_only(promise):
-        gblock = _block_of(spec, klanes, 0)
-        _, lblock = _owner_local(spec, gblock)
-        tk, tv, st, ok = kops.bulk_insert(
-            state.tkeys, state.tvals, state.status, lblock, klanes, vlanes,
-            valid, mode, impl=spec.impl)
-        costs.record("hashmap.insert", costs.Cost(local=n))
-        return HashMapState(tk, tv, st), ok
+        if local_only(promise):
+            gblock = _block_of(spec, klanes, 0)
+            _, lblock = _owner_local(spec, gblock)
+            tk, tv, st, ok = kops.bulk_insert(
+                state.tkeys, state.tvals, state.status, lblock, klanes, vlanes,
+                valid, mode, impl=spec.impl)
+            costs.record("hashmap.insert", costs.Cost(local=n))
+            return HashMapState(tk, tv, st), ok
 
-    atomic = fully_atomic_hashmap(promise)
-    pending = valid
-    success = jnp.zeros((n,), bool)
-    new_state = state
-    # success replies ride the plan's inverse permutation (through the
-    # chosen transport); a fire-and-forget insert declares no reply
-    rl = 1 if (return_success or attempts > 1) else 0
-    for a in range(max(1, attempts)):
-        gblock = _block_of(spec, klanes, a)
-        owner, lblock = _owner_local(spec, gblock)
-        body = jnp.concatenate(
-            [lblock.astype(_U32)[:, None], klanes, vlanes], axis=1)
-        plan = ExchangePlan(name="hashmap.insert")
-        h = plan.add(body, owner, capacity, reply_lanes=rl, valid=pending,
-                     op_name="hashmap.insert")
-        c = plan.commit(backend, impl=spec.impl, max_rounds=max_rounds,
-                        transport=transport, dead_ranks=dead_ranks,
-                        integrity=integrity)
-        res = c.view(h)
+        atomic = fully_atomic_hashmap(promise)
+        pending = valid
+        success = jnp.zeros((n,), bool)
+        new_state = state
+        # success replies ride the plan's inverse permutation (through the
+        # chosen transport); a fire-and-forget insert declares no reply
+        rl = 1 if (return_success or attempts > 1) else 0
+        for a in range(max(1, attempts)):
+            gblock = _block_of(spec, klanes, a)
+            owner, lblock = _owner_local(spec, gblock)
+            body = jnp.concatenate(
+                [lblock.astype(_U32)[:, None], klanes, vlanes], axis=1)
+            plan = ExchangePlan(name="hashmap.insert")
+            h = plan.add(body, owner, capacity, reply_lanes=rl, valid=pending,
+                         op_name="hashmap.insert")
+            c = plan.commit(backend, impl=spec.impl, max_rounds=max_rounds,
+                            transport=transport, dead_ranks=dead_ranks,
+                            integrity=integrity)
+            res = c.view(h)
 
-        tk, tv, st = new_state
-        if atomic:
-            # paper 5.1.3: CAS free->reserved ... XOR ->ready.  The state
-            # machine is owner-serialized here, but we execute the reserve
-            # pass so its traffic is real: a net-zero RMW on the status
-            # word of every touched block.
-            rb = jnp.where(res.valid, res.payload[:, 0].astype(_I32), 0)
-            st = st.at[rb].add(_READ_BIT, mode="drop")
-            st = st.at[rb].add(_U32(0) - _READ_BIT, mode="drop")
-        # the arrival segment feeds the probe directly (DESIGN.md §1.10)
-        tk, tv, st, ok_here = kops.bulk_insert_arrivals(
-            tk, tv, st, res.payload, res.valid, mode, impl=spec.impl)
-        new_state = HashMapState(tk, tv, st)
+            tk, tv, st = new_state
+            if atomic:
+                # paper 5.1.3: CAS free->reserved ... XOR ->ready.  The state
+                # machine is owner-serialized here, but we execute the reserve
+                # pass so its traffic is real: a net-zero RMW on the status
+                # word of every touched block.
+                rb = jnp.where(res.valid, res.payload[:, 0].astype(_I32), 0)
+                st = st.at[rb].add(_READ_BIT, mode="drop")
+                st = st.at[rb].add(_U32(0) - _READ_BIT, mode="drop")
+            # the arrival segment feeds the probe directly (DESIGN.md §1.10)
+            tk, tv, st, ok_here = kops.bulk_insert_arrivals(
+                tk, tv, st, res.payload, res.valid, mode, impl=spec.impl)
+            new_state = HashMapState(tk, tv, st)
 
-        if rl:
-            c.set_reply(h, ok_here.astype(_U32))
-            back, _ = c.finish(backend)[h]
-            ok_src = (back[:, 0] == 1) & pending
-            success = success | ok_src
-            pending = pending & ~ok_src
-        else:
-            break
-    costs.record("hashmap.insert",
-                 costs.Cost(A=2 if atomic else 1, W=n))
-    return new_state, (success if (return_success or attempts > 1) else None)
+            if rl:
+                c.set_reply(h, ok_here.astype(_U32))
+                back, _ = c.finish(backend)[h]
+                ok_src = (back[:, 0] == 1) & pending
+                success = success | ok_src
+                pending = pending & ~ok_src
+            else:
+                break
+        costs.record("hashmap.insert",
+                     costs.Cost(A=2 if atomic else 1, W=n))
+        return new_state, (success if (return_success or attempts > 1)
+                           else None)
 
 
 def _find_speculative(backend: Backend, spec: HashMapSpec,
@@ -286,66 +289,69 @@ def find(backend: Backend, spec: HashMapSpec, state: HashMapState,
     subsets; found keys always carry correct values either way.
     ``Promise.FINE`` in the promise forces the sequential schedule.
     """
-    validate(promise)
-    if fine_grained(promise):
-        speculative = False
-    klanes = spec.key_packer.pack(keys)
-    n = klanes.shape[0]
-    if valid is None:
-        valid = jnp.ones((n,), bool)
+    with costs.scope("hashmap.find"):
+        validate(promise)
+        if fine_grained(promise):
+            speculative = False
+        klanes = spec.key_packer.pack(keys)
+        n = klanes.shape[0]
+        if valid is None:
+            valid = jnp.ones((n,), bool)
 
-    if local_only(promise):
-        gblock = _block_of(spec, klanes, 0)
-        _, lblock = _owner_local(spec, gblock)
-        found, vlanes = kops.bulk_find(state.tkeys, state.tvals, state.status,
-                                       lblock, klanes, valid, impl=spec.impl)
-        costs.record("hashmap.find", costs.Cost(local=n))
-        return state, spec.val_packer.unpack(vlanes), found
+        if local_only(promise):
+            gblock = _block_of(spec, klanes, 0)
+            _, lblock = _owner_local(spec, gblock)
+            found, vlanes = kops.bulk_find(state.tkeys, state.tvals,
+                                           state.status, lblock, klanes,
+                                           valid, impl=spec.impl)
+            costs.record("hashmap.find", costs.Cost(local=n))
+            return state, spec.val_packer.unpack(vlanes), found
 
-    atomic = not find_only(promise)
-    if speculative and attempts == 2:
-        return _find_speculative(backend, spec, state, klanes, capacity,
-                                 valid, atomic, max_rounds=max_rounds,
-                                 transport=transport, dead_ranks=dead_ranks,
-                                 integrity=integrity)
-    pending = valid
-    found_all = jnp.zeros((n,), bool)
-    vals_all = jnp.zeros((n, spec.val_packer.lanes), _U32)
-    for a in range(max(1, attempts)):
-        gblock = _block_of(spec, klanes, a)
-        owner, lblock = _owner_local(spec, gblock)
-        body = jnp.concatenate([lblock.astype(_U32)[:, None], klanes], axis=1)
-        plan = ExchangePlan(name="hashmap.find")
-        h = plan.add(body, owner, capacity,
-                     reply_lanes=spec.val_packer.lanes + 1, valid=pending,
-                     op_name="hashmap.find")
-        c = plan.commit(backend, impl=spec.impl, max_rounds=max_rounds,
-                        transport=transport, dead_ranks=dead_ranks,
-                        integrity=integrity)
-        res = c.view(h)
-        tk, tv, st = state
-        if atomic:
-            # fetch-and-or a read bit, read, fetch-and-and it away
-            rb = jnp.where(res.valid, res.payload[:, 0].astype(_I32), 0)
-            st = st.at[rb].add(_READ_BIT, mode="drop")
-        found_here, vlanes = kops.bulk_find_arrivals(tk, tv, st, res.payload,
-                                                     res.valid,
-                                                     impl=spec.impl)
-        if atomic:
-            st = st.at[rb].add(_U32(0) - _READ_BIT, mode="drop")
-            state = HashMapState(tk, tv, st)
-        c.set_reply(h, jnp.concatenate(
-            [vlanes, found_here.astype(_U32)[:, None]], axis=1))
-        back, _ = c.finish(backend)[h]
-        got = (back[:, -1] == 1) & pending
-        vals_all = jnp.where(got[:, None], back[:, :-1], vals_all)
-        found_all = found_all | got
-        pending = pending & ~got
-        if attempts == 1:
-            break
-    costs.record("hashmap.find",
-                 costs.Cost(A=2 if atomic else 0, R=n))
-    return state, spec.val_packer.unpack(vals_all), found_all
+        atomic = not find_only(promise)
+        if speculative and attempts == 2:
+            return _find_speculative(backend, spec, state, klanes, capacity,
+                                     valid, atomic, max_rounds=max_rounds,
+                                     transport=transport,
+                                     dead_ranks=dead_ranks,
+                                     integrity=integrity)
+        pending = valid
+        found_all = jnp.zeros((n,), bool)
+        vals_all = jnp.zeros((n, spec.val_packer.lanes), _U32)
+        for a in range(max(1, attempts)):
+            gblock = _block_of(spec, klanes, a)
+            owner, lblock = _owner_local(spec, gblock)
+            body = jnp.concatenate([lblock.astype(_U32)[:, None], klanes],
+                                   axis=1)
+            plan = ExchangePlan(name="hashmap.find")
+            h = plan.add(body, owner, capacity,
+                         reply_lanes=spec.val_packer.lanes + 1, valid=pending,
+                         op_name="hashmap.find")
+            c = plan.commit(backend, impl=spec.impl, max_rounds=max_rounds,
+                            transport=transport, dead_ranks=dead_ranks,
+                            integrity=integrity)
+            res = c.view(h)
+            tk, tv, st = state
+            if atomic:
+                # fetch-and-or a read bit, read, fetch-and-and it away
+                rb = jnp.where(res.valid, res.payload[:, 0].astype(_I32), 0)
+                st = st.at[rb].add(_READ_BIT, mode="drop")
+            found_here, vlanes = kops.bulk_find_arrivals(
+                tk, tv, st, res.payload, res.valid, impl=spec.impl)
+            if atomic:
+                st = st.at[rb].add(_U32(0) - _READ_BIT, mode="drop")
+                state = HashMapState(tk, tv, st)
+            c.set_reply(h, jnp.concatenate(
+                [vlanes, found_here.astype(_U32)[:, None]], axis=1))
+            back, _ = c.finish(backend)[h]
+            got = (back[:, -1] == 1) & pending
+            vals_all = jnp.where(got[:, None], back[:, :-1], vals_all)
+            found_all = found_all | got
+            pending = pending & ~got
+            if attempts == 1:
+                break
+        costs.record("hashmap.find",
+                     costs.Cost(A=2 if atomic else 0, R=n))
+        return state, spec.val_packer.unpack(vals_all), found_all
 
 
 def find_insert(backend: Backend, spec: HashMapSpec, state: HashMapState,
@@ -382,68 +388,82 @@ def find_insert(backend: Backend, spec: HashMapSpec, state: HashMapState,
     when the call returns, and everything the caller traces before
     ``finish()`` overlaps with it.
     """
-    validate(promise)
-    # per-op atomicity gates mirror the standalone ops exactly, so the
-    # FINE oracle and the fused schedule agree on the A counts and the
-    # status-word traffic for ANY promise, not just find_insert
-    find_atomic = not find_only(promise)
-    ins_atomic = fully_atomic_hashmap(promise)
-    if fine_grained(promise) and not async_:
-        state, vals, found = find(backend, spec, state, find_keys, capacity,
-                                  promise=promise, valid=find_valid,
-                                  attempts=1, max_rounds=max_rounds,
-                                  transport=transport, dead_ranks=dead_ranks,
-                                  integrity=integrity)
-        state, ok = insert(backend, spec, state, ins_keys, ins_vals, capacity,
-                           promise=promise, valid=ins_valid, mode=mode,
-                           attempts=1, return_success=True,
-                           max_rounds=max_rounds, transport=transport,
-                           dead_ranks=dead_ranks, integrity=integrity)
-        return state, vals, found, ok
-    if fine_grained(promise):
-        # split-phase FINE stays the sequential oracle: commit eagerly,
-        # hand completion back through the same future type
-        sync = find_insert(backend, spec, state, find_keys, ins_keys,
-                           ins_vals, capacity, promise=promise,
-                           find_valid=find_valid, ins_valid=ins_valid,
-                           mode=mode, max_rounds=max_rounds,
-                           transport=transport, dead_ranks=dead_ranks,
-                           integrity=integrity)
-        return PendingResult(lambda: sync)
+    with costs.scope("hashmap.find_insert"):
+        validate(promise)
+        # per-op atomicity gates mirror the standalone ops exactly, so the
+        # FINE oracle and the fused schedule agree on the A counts and the
+        # status-word traffic for ANY promise, not just find_insert
+        find_atomic = not find_only(promise)
+        ins_atomic = fully_atomic_hashmap(promise)
+        if fine_grained(promise) and not async_:
+            state, vals, found = find(backend, spec, state, find_keys,
+                                      capacity, promise=promise,
+                                      valid=find_valid, attempts=1,
+                                      max_rounds=max_rounds,
+                                      transport=transport,
+                                      dead_ranks=dead_ranks,
+                                      integrity=integrity)
+            state, ok = insert(backend, spec, state, ins_keys, ins_vals,
+                               capacity,
+                               promise=promise, valid=ins_valid, mode=mode,
+                               attempts=1, return_success=True,
+                               max_rounds=max_rounds, transport=transport,
+                               dead_ranks=dead_ranks, integrity=integrity)
+            return state, vals, found, ok
+        if fine_grained(promise):
+            # split-phase FINE stays the sequential oracle: commit eagerly,
+            # hand completion back through the same future type
+            sync = find_insert(backend, spec, state, find_keys, ins_keys,
+                               ins_vals, capacity, promise=promise,
+                               find_valid=find_valid, ins_valid=ins_valid,
+                               mode=mode, max_rounds=max_rounds,
+                               transport=transport, dead_ranks=dead_ranks,
+                               integrity=integrity)
+            return PendingResult(lambda: sync)
 
-    kf = spec.key_packer.pack(find_keys)
-    ki = spec.key_packer.pack(ins_keys)
-    vi = spec.val_packer.pack(ins_vals)
-    nf, ni = kf.shape[0], ki.shape[0]
-    lk = spec.key_packer.lanes
-    if find_valid is None:
-        find_valid = jnp.ones((nf,), bool)
-    if ins_valid is None:
-        ins_valid = jnp.ones((ni,), bool)
-    owner_f, lb_f = _owner_local(spec, _block_of(spec, kf, 0))
-    owner_i, lb_i = _owner_local(spec, _block_of(spec, ki, 0))
+        kf = spec.key_packer.pack(find_keys)
+        ki = spec.key_packer.pack(ins_keys)
+        vi = spec.val_packer.pack(ins_vals)
+        nf, ni = kf.shape[0], ki.shape[0]
+        lk = spec.key_packer.lanes
+        if find_valid is None:
+            find_valid = jnp.ones((nf,), bool)
+        if ins_valid is None:
+            ins_valid = jnp.ones((ni,), bool)
+        owner_f, lb_f = _owner_local(spec, _block_of(spec, kf, 0))
+        owner_i, lb_i = _owner_local(spec, _block_of(spec, ki, 0))
 
-    plan = ExchangePlan(name="hashmap.find_insert")
-    hf = plan.add(jnp.concatenate([lb_f.astype(_U32)[:, None], kf], axis=1),
-                  owner_f, capacity, reply_lanes=spec.val_packer.lanes + 1,
-                  valid=find_valid, op_name="hashmap.find")
-    hi = plan.add(jnp.concatenate([lb_i.astype(_U32)[:, None], ki, vi],
-                                  axis=1),
-                  owner_i, capacity, reply_lanes=1,
-                  valid=ins_valid, op_name="hashmap.insert")
-    if async_:
-        pend = plan.commit_async(backend, impl=spec.impl,
-                                 max_rounds=max_rounds, transport=transport,
-                                 dead_ranks=dead_ranks, integrity=integrity)
-        return PendingResult(lambda: _find_insert_complete(
-            backend, spec, state, pend.finish(backend), hf, hi, lk,
-            find_valid, ins_valid, mode, find_atomic, ins_atomic, nf, ni))
-    c = plan.commit(backend, impl=spec.impl, max_rounds=max_rounds,
-                    transport=transport, dead_ranks=dead_ranks,
-                    integrity=integrity)
-    return _find_insert_complete(backend, spec, state, c, hf, hi, lk,
-                                 find_valid, ins_valid, mode,
-                                 find_atomic, ins_atomic, nf, ni)
+        plan = ExchangePlan(name="hashmap.find_insert")
+        hf = plan.add(jnp.concatenate([lb_f.astype(_U32)[:, None], kf],
+                                      axis=1),
+                      owner_f, capacity, reply_lanes=spec.val_packer.lanes + 1,
+                      valid=find_valid, op_name="hashmap.find")
+        hi = plan.add(jnp.concatenate([lb_i.astype(_U32)[:, None], ki, vi],
+                                      axis=1),
+                      owner_i, capacity, reply_lanes=1,
+                      valid=ins_valid, op_name="hashmap.insert")
+        if async_:
+            pend = plan.commit_async(backend, impl=spec.impl,
+                                     max_rounds=max_rounds,
+                                     transport=transport,
+                                     dead_ranks=dead_ranks,
+                                     integrity=integrity)
+
+            def complete():
+                # the completion tail is traced at finish(), outside the
+                # scope above: it takes the op's name again
+                with costs.scope("hashmap.find_insert"):
+                    return _find_insert_complete(
+                        backend, spec, state, pend.finish(backend), hf, hi,
+                        lk, find_valid, ins_valid, mode, find_atomic,
+                        ins_atomic, nf, ni)
+            return PendingResult(complete)
+        c = plan.commit(backend, impl=spec.impl, max_rounds=max_rounds,
+                        transport=transport, dead_ranks=dead_ranks,
+                        integrity=integrity)
+        return _find_insert_complete(backend, spec, state, c, hf, hi, lk,
+                                     find_valid, ins_valid, mode,
+                                     find_atomic, ins_atomic, nf, ni)
 
 
 def _find_insert_complete(backend, spec, state, c, hf, hi, lk,

@@ -63,12 +63,13 @@ class QueueState(NamedTuple):
 
 def queue_create(backend: Backend, capacity: int, value_spec,
                  circular: bool = False) -> tuple[QueueSpec, QueueState]:
-    packer = packer_for(value_spec)
-    spec = QueueSpec(capacity, packer, circular)
-    z = lambda: jnp.zeros((1,), _I32)
-    state = QueueState(jnp.zeros((capacity, packer.lanes), _U32),
-                       z(), z(), z(), z())
-    return spec, state
+    with costs.scope("queue.create"):
+        packer = packer_for(value_spec)
+        spec = QueueSpec(capacity, packer, circular)
+        z = lambda: jnp.zeros((1,), _I32)
+        state = QueueState(jnp.zeros((capacity, packer.lanes), _U32),
+                           z(), z(), z(), z())
+        return spec, state
 
 
 def size(state: QueueState) -> jax.Array:
@@ -123,52 +124,54 @@ def push(backend: Backend, spec: QueueSpec, state: QueueState,
     ``overflow="carry"`` such items never receive an accept ack, so the
     carry mask re-injects them and a retry heals transient corruption.
     """
-    validate(promise)
-    if overflow not in ("drop", "carry"):
-        raise ValueError(
-            f'queue.push overflow must be "drop" or "carry", '
-            f"got {overflow!r}")
-    lanes = spec.packer.pack(values)
-    n = lanes.shape[0]
-    if valid is None:
-        valid = jnp.ones((n,), bool)
+    with costs.scope("queue.push"):
+        validate(promise)
+        if overflow not in ("drop", "carry"):
+            raise ValueError(
+                f'queue.push overflow must be "drop" or "carry", '
+                f"got {overflow!r}")
+        lanes = spec.packer.pack(values)
+        n = lanes.shape[0]
+        if valid is None:
+            valid = jnp.ones((n,), bool)
 
-    if promise & Promise.LOCAL:
-        # local push: no collectives, CPU-only ring append (paper 4c);
-        # carry needs no reply wire here — the accept mask IS local
-        costs.record("queue.push", costs.Cost(local=n))
-        state, pushed, full_drop, accept = _append(spec, state, lanes, valid)
+        if promise & Promise.LOCAL:
+            # local push: no collectives, CPU-only ring append (paper 4c);
+            # carry needs no reply wire here — the accept mask IS local
+            costs.record("queue.push", costs.Cost(local=n))
+            state, pushed, full_drop, accept = _append(spec, state, lanes,
+                                                       valid)
+            if overflow == "carry":
+                return state, pushed, jnp.int32(0), valid & ~accept
+            return state, pushed, full_drop
+
         if overflow == "carry":
-            return state, pushed, jnp.int32(0), valid & ~accept
-        return state, pushed, full_drop
+            plan = ExchangePlan(name="queue.push")
+            h = plan.add(lanes, dest, capacity, reply_lanes=1, valid=valid,
+                         op_name="queue.push")
+            c = plan.commit(backend, impl=impl, max_rounds=max_rounds,
+                            transport=transport, dead_ranks=dead_ranks,
+                            integrity=integrity)
+            res = c.view(h)
+            state, pushed, _, accept = _append(spec, state, res.payload,
+                                               res.valid)
+            c.set_reply(h, accept.astype(_U32))
+            out, answered = c.finish(backend)[h]
+            a = _amo_count(spec, promise)
+            costs.record("queue.push", costs.Cost(A=a, W=n))
+            landed = answered & (out[:, 0] == 1) & valid
+            return state, pushed, jnp.int32(0), valid & ~landed
 
-    if overflow == "carry":
-        plan = ExchangePlan(name="queue.push")
-        h = plan.add(lanes, dest, capacity, reply_lanes=1, valid=valid,
-                     op_name="queue.push")
-        c = plan.commit(backend, impl=impl, max_rounds=max_rounds,
-                        transport=transport, dead_ranks=dead_ranks,
-                        integrity=integrity)
-        res = c.view(h)
-        state, pushed, _, accept = _append(spec, state, res.payload,
-                                           res.valid)
-        c.set_reply(h, accept.astype(_U32))
-        out, answered = c.finish(backend)[h]
+        res = route(backend, lanes, dest, capacity, valid=valid,
+                    op_name="queue.push", impl=impl, max_rounds=max_rounds,
+                    transport=transport, dead_ranks=dead_ranks,
+                    integrity=integrity)
+        state, pushed, full_drop, _ = _append(spec, state, res.payload,
+                                              res.valid)
         a = _amo_count(spec, promise)
         costs.record("queue.push", costs.Cost(A=a, W=n))
-        landed = answered & (out[:, 0] == 1) & valid
-        return state, pushed, jnp.int32(0), valid & ~landed
-
-    res = route(backend, lanes, dest, capacity, valid=valid,
-                op_name="queue.push", impl=impl, max_rounds=max_rounds,
-                transport=transport, dead_ranks=dead_ranks,
-                integrity=integrity)
-    state, pushed, full_drop, _ = _append(spec, state, res.payload,
-                                          res.valid)
-    a = _amo_count(spec, promise)
-    costs.record("queue.push", costs.Cost(A=a, W=n))
-    dropped = res.dropped + backend.psum(full_drop)
-    return state, pushed, dropped
+        dropped = res.dropped + backend.psum(full_drop)
+        return state, pushed, dropped
 
 
 def _append(spec: QueueSpec, state: QueueState, rows: jax.Array,
@@ -240,30 +243,31 @@ def pop(backend: Backend, spec: QueueSpec, state: QueueState,
     deterministic requester order (the FAA analogue).  Returns
     (state, values, got_mask).
     """
-    validate(promise)
-    src = _src_ranks(src, n)
+    with costs.scope("queue.pop"):
+        validate(promise)
+        src = _src_ranks(src, n)
 
-    if promise & Promise.LOCAL:
-        return local_nonatomic_pop(spec, state, n)
+        if promise & Promise.LOCAL:
+            return local_nonatomic_pop(spec, state, n)
 
-    # unit requests: one row per wanted item (per-(src,dst) capacity = n);
-    # a single-flow plan so the grant reply rides the transport's exact
-    # inverse hop sequence (dense: the one inverse all-to-all)
-    plan = ExchangePlan(name="queue.pop")
-    h = plan.add(jnp.zeros((n, 1), _U32), src, n,
-                 reply_lanes=spec.lanes + 1, op_name="queue.pop")
-    c = plan.commit(backend, impl=impl, max_rounds=max_rounds,
-                    transport=transport, dead_ranks=dead_ranks,
-                    integrity=integrity)
-    req = c.view(h)
-    new, body = _grant(spec, state, req.valid, promise)
-    c.set_reply(h, body)
-    out, _ = c.finish(backend)[h]
-    got = out[:, -1] == 1
-    values = spec.packer.unpack(out[:, :-1])
-    a = _amo_count(spec, promise)
-    costs.record("queue.pop", costs.Cost(A=a, R=n))
-    return new, values, got
+        # unit requests: one row per wanted item (per-(src,dst) capacity = n);
+        # a single-flow plan so the grant reply rides the transport's exact
+        # inverse hop sequence (dense: the one inverse all-to-all)
+        plan = ExchangePlan(name="queue.pop")
+        h = plan.add(jnp.zeros((n, 1), _U32), src, n,
+                     reply_lanes=spec.lanes + 1, op_name="queue.pop")
+        c = plan.commit(backend, impl=impl, max_rounds=max_rounds,
+                        transport=transport, dead_ranks=dead_ranks,
+                        integrity=integrity)
+        req = c.view(h)
+        new, body = _grant(spec, state, req.valid, promise)
+        c.set_reply(h, body)
+        out, _ = c.finish(backend)[h]
+        got = out[:, -1] == 1
+        values = spec.packer.unpack(out[:, :-1])
+        a = _amo_count(spec, promise)
+        costs.record("queue.pop", costs.Cost(A=a, R=n))
+        return new, values, got
 
 
 def push_pop(backend: Backend, spec: QueueSpec, state: QueueState,
@@ -305,68 +309,80 @@ def push_pop(backend: Backend, spec: QueueSpec, state: QueueState,
     ``finish()`` yields the same tuple — the request wire overlaps with
     whatever the caller traces before finishing.
     """
-    validate(promise)
-    if overflow not in ("drop", "carry"):
-        raise ValueError(
-            f'queue.push_pop overflow must be "drop" or "carry", '
-            f"got {overflow!r}")
-    if async_ and fine_grained(promise):
-        # split-phase FINE stays the sequential oracle: run eagerly,
-        # hand completion back through the same future type
-        sync = push_pop(backend, spec, state, values, dest, capacity, n,
-                        src, valid=valid, promise=promise,
-                        max_rounds=max_rounds, overflow=overflow,
-                        transport=transport, dead_ranks=dead_ranks,
-                        integrity=integrity, impl=impl)
-        return PendingResult(lambda: sync)
-    if fine_grained(promise):
-        if overflow == "carry":
-            state, pushed, dropped, carry = push(
-                backend, spec, state, values, dest, capacity, valid=valid,
-                promise=promise, max_rounds=max_rounds, overflow="carry",
-                transport=transport, dead_ranks=dead_ranks,
-                integrity=integrity, impl=impl)
-            state, out, got = pop(backend, spec, state, n, src,
-                                  promise=promise, max_rounds=max_rounds,
-                                  transport=transport, dead_ranks=dead_ranks,
-                                  integrity=integrity, impl=impl)
-            return state, pushed, dropped, out, got, carry
-        state, pushed, dropped = push(backend, spec, state, values, dest,
-                                      capacity, valid=valid, promise=promise,
-                                      max_rounds=max_rounds,
+    with costs.scope("queue.push_pop"):
+        validate(promise)
+        if overflow not in ("drop", "carry"):
+            raise ValueError(
+                f'queue.push_pop overflow must be "drop" or "carry", '
+                f"got {overflow!r}")
+        if async_ and fine_grained(promise):
+            # split-phase FINE stays the sequential oracle: run eagerly,
+            # hand completion back through the same future type
+            sync = push_pop(backend, spec, state, values, dest, capacity, n,
+                            src, valid=valid, promise=promise,
+                            max_rounds=max_rounds, overflow=overflow,
+                            transport=transport, dead_ranks=dead_ranks,
+                            integrity=integrity, impl=impl)
+            return PendingResult(lambda: sync)
+        if fine_grained(promise):
+            if overflow == "carry":
+                state, pushed, dropped, carry = push(
+                    backend, spec, state, values, dest, capacity, valid=valid,
+                    promise=promise, max_rounds=max_rounds, overflow="carry",
+                    transport=transport, dead_ranks=dead_ranks,
+                    integrity=integrity, impl=impl)
+                state, out, got = pop(backend, spec, state, n, src,
+                                      promise=promise, max_rounds=max_rounds,
                                       transport=transport,
                                       dead_ranks=dead_ranks,
                                       integrity=integrity, impl=impl)
-        state, out, got = pop(backend, spec, state, n, src, promise=promise,
-                              max_rounds=max_rounds, transport=transport,
-                              dead_ranks=dead_ranks, integrity=integrity,
-                              impl=impl)
-        return state, pushed, dropped, out, got
+                return state, pushed, dropped, out, got, carry
+            state, pushed, dropped = push(backend, spec, state, values, dest,
+                                          capacity, valid=valid,
+                                          promise=promise,
+                                          max_rounds=max_rounds,
+                                          transport=transport,
+                                          dead_ranks=dead_ranks,
+                                          integrity=integrity, impl=impl)
+            state, out, got = pop(backend, spec, state, n, src,
+                                  promise=promise, max_rounds=max_rounds,
+                                  transport=transport,
+                                  dead_ranks=dead_ranks, integrity=integrity,
+                                  impl=impl)
+            return state, pushed, dropped, out, got
 
-    lanes = spec.packer.pack(values)
-    nv = lanes.shape[0]
-    if valid is None:
-        valid = jnp.ones((nv,), bool)
-    src = _src_ranks(src, n)
-    carrying = overflow == "carry"
+        lanes = spec.packer.pack(values)
+        nv = lanes.shape[0]
+        if valid is None:
+            valid = jnp.ones((nv,), bool)
+        src = _src_ranks(src, n)
+        carrying = overflow == "carry"
 
-    plan = ExchangePlan(name="queue.push_pop")
-    hp = plan.add(lanes, dest, capacity, valid=valid,
-                  reply_lanes=1 if carrying else 0, op_name="queue.push")
-    hq = plan.add(jnp.zeros((n, 1), _U32), src, n,
-                  reply_lanes=spec.lanes + 1, op_name="queue.pop")
-    if async_:
-        pend = plan.commit_async(backend, impl=impl, max_rounds=max_rounds,
-                                 transport=transport, dead_ranks=dead_ranks,
-                                 integrity=integrity)
-        return PendingResult(lambda: _push_pop_complete(
-            backend, spec, state, pend.finish(backend), hp, hq, valid,
-            promise, carrying, nv, n))
-    c = plan.commit(backend, impl=impl, max_rounds=max_rounds,
-                    transport=transport, dead_ranks=dead_ranks,
-                    integrity=integrity)
-    return _push_pop_complete(backend, spec, state, c, hp, hq, valid,
-                              promise, carrying, nv, n)
+        plan = ExchangePlan(name="queue.push_pop")
+        hp = plan.add(lanes, dest, capacity, valid=valid,
+                      reply_lanes=1 if carrying else 0, op_name="queue.push")
+        hq = plan.add(jnp.zeros((n, 1), _U32), src, n,
+                      reply_lanes=spec.lanes + 1, op_name="queue.pop")
+        if async_:
+            pend = plan.commit_async(backend, impl=impl,
+                                     max_rounds=max_rounds,
+                                     transport=transport,
+                                     dead_ranks=dead_ranks,
+                                     integrity=integrity)
+
+            def complete():
+                # the completion tail is traced at finish(), outside the
+                # scope above: it takes the op's name again
+                with costs.scope("queue.push_pop"):
+                    return _push_pop_complete(
+                        backend, spec, state, pend.finish(backend), hp, hq,
+                        valid, promise, carrying, nv, n)
+            return PendingResult(complete)
+        c = plan.commit(backend, impl=impl, max_rounds=max_rounds,
+                        transport=transport, dead_ranks=dead_ranks,
+                        integrity=integrity)
+        return _push_pop_complete(backend, spec, state, c, hp, hq, valid,
+                                  promise, carrying, nv, n)
 
 
 def _push_pop_complete(backend, spec, state, c, hp, hq, valid, promise,
@@ -416,12 +432,13 @@ def local_nonatomic_pop(spec: QueueSpec, state: QueueState, n: int):
 def local_drain(spec: QueueSpec, state: QueueState):
     """Read the whole local ring in FIFO order (the ``as_vector`` of the
     paper's Fig. 3); state unchanged.  Returns (rows, valid)."""
-    take = jnp.arange(spec.capacity, dtype=_I32)
-    avail = state.tail[0] - state.head[0]
-    got = take < avail
-    idx = (state.head[0] + take) % spec.capacity
-    rows = jnp.where(got[:, None], state.data[idx], 0)
-    return spec.packer.unpack(rows), got
+    with costs.scope("queue.drain"):
+        take = jnp.arange(spec.capacity, dtype=_I32)
+        avail = state.tail[0] - state.head[0]
+        got = take < avail
+        idx = (state.head[0] + take) % spec.capacity
+        rows = jnp.where(got[:, None], state.data[idx], 0)
+        return spec.packer.unpack(rows), got
 
 
 def export_state(spec: QueueSpec, state: QueueState) -> dict:

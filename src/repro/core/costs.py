@@ -16,6 +16,14 @@ bytes and collective counts next to wall time.
 
 Costs are trace-time (static) values: they depend only on shapes and
 promises, never on traced data, so accounting lives outside jit.
+
+:func:`scope` names the same operations inside the compiled program: a
+container op, an exchange phase, a transport direction or an owner probe
+wraps its work in ``jax.named_scope("bcl." + op)``, under the op name
+its cost entry uses, so every HLO instruction it emits carries the name
+in its ``op_name`` metadata and a profiler trace can charge device time
+to the layer that issued it (DESIGN.md section 1.11).  A scope is
+metadata only: it adds no op and moves no byte, so it has no switch.
 """
 
 from __future__ import annotations
@@ -23,6 +31,8 @@ from __future__ import annotations
 import dataclasses
 from contextlib import contextmanager
 from typing import Iterator
+
+import jax
 
 
 @dataclasses.dataclass
@@ -127,3 +137,13 @@ def recording() -> Iterator[CostLog]:
         yield log
     finally:
         _ACTIVE.pop()
+
+
+def scope(op: str):
+    """Context manager naming every op traced inside it ``bcl.<op>``.
+
+    ``op`` is a cost-log op name (``"queue.push"``, ``"exchange.bin"``),
+    so a layer's device time and its static cost join on one key; it
+    holds no ``/``, which separates the levels of an ``op_name``.
+    """
+    return jax.named_scope("bcl." + op)

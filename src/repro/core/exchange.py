@@ -330,16 +330,19 @@ class ExchangePlan:
         ack/carry retry path instead of poisoning owner state.  Both
         default off, leaving the wire byte-identical to a plain commit.
         """
-        dead, transport = self._precommit(backend, max_rounds, overflow,
-                                          dead_ranks, transport)
-        if fine_grained(self.promise):
-            return self._commit_fine(backend, impl, int(max_rounds),
-                                     overflow, transport, dead, integrity)
-        st = self._stage_fused(backend, impl, int(max_rounds), overflow,
-                               transport, dead, integrity)
-        segments, extra_drop, tctx = transport.request(backend, st.args)
-        return self._finalize_fused(backend, st, segments, extra_drop,
-                                    tctx, transport)
+        with costs.scope("exchange.commit"):
+            dead, transport = self._precommit(backend, max_rounds, overflow,
+                                              dead_ranks, transport)
+            if fine_grained(self.promise):
+                return self._commit_fine(backend, impl, int(max_rounds),
+                                         overflow, transport, dead, integrity)
+            st = self._stage_fused(backend, impl, int(max_rounds), overflow,
+                                   transport, dead, integrity)
+            with costs.scope("transport.request"):
+                segments, extra_drop, tctx = transport.request(backend,
+                                                               st.args)
+            return self._finalize_fused(backend, st, segments, extra_drop,
+                                        tctx, transport)
 
     def commit_async(self, backend: Backend, impl: str = "auto",
                      max_rounds: int = 1,
@@ -371,17 +374,19 @@ class ExchangePlan:
         ``overlap_launches``) and the returned PendingPlan is already
         complete — ``finish()`` just unwraps it.
         """
-        dead, transport = self._precommit(backend, max_rounds, overflow,
-                                          dead_ranks, transport)
-        if fine_grained(self.promise):
-            return PendingPlan(self, committed=self._commit_fine(
-                backend, impl, int(max_rounds), overflow, transport,
-                dead, integrity))
-        st = self._stage_fused(backend, impl, int(max_rounds), overflow,
-                               transport, dead, integrity)
-        handle = transport.request_start(backend, st.args)
-        return PendingPlan(self, staged=st, handle=handle,
-                           transport=transport)
+        with costs.scope("exchange.commit"):
+            dead, transport = self._precommit(backend, max_rounds, overflow,
+                                              dead_ranks, transport)
+            if fine_grained(self.promise):
+                return PendingPlan(self, committed=self._commit_fine(
+                    backend, impl, int(max_rounds), overflow, transport,
+                    dead, integrity))
+            st = self._stage_fused(backend, impl, int(max_rounds), overflow,
+                                   transport, dead, integrity)
+            with costs.scope("transport.request"):
+                handle = transport.request_start(backend, st.args)
+            return PendingPlan(self, staged=st, handle=handle,
+                               transport=transport)
 
     def _precommit(self, backend: Backend, max_rounds, overflow,
                    dead_ranks, transport):
@@ -478,8 +483,9 @@ class ExchangePlan:
         # re-binning passes inside a transport record their own).
         costs.record("exchange.bin",
                      costs.Cost(local=int(dest_all.shape[0])))
-        counts, offsets = kops.multi_bin_offsets(
-            dest_all, flow_id, nprocs, nflows, valid_all, impl=impl)
+        with costs.scope("exchange.bin"):
+            counts, offsets = kops.multi_bin_offsets(
+                dest_all, flow_id, nprocs, nflows, valid_all, impl=impl)
         caps_arr = jnp.asarray(caps, _I32)
         rounds_arr = jnp.asarray(rounds_f, _I32)
         eff_arr = caps_arr * rounds_arr                # effective R_f*C_f
@@ -778,50 +784,52 @@ class CommittedPlan:
         for every flow with ``reply_lanes > 0``; replies land aligned
         with each flow's *original* request batch.
         """
-        if self._finished:
-            # callers must cache the returned dict; a second finish would
-            # launch a duplicate collective and double-record costs
-            raise ValueError("CommittedPlan already finished")
-        flows = self._plan._flows
-        replying = [fi for fi, f in enumerate(flows) if f.reply_lanes > 0]
-        for fi in replying:
-            if fi not in self._replies:
-                raise ValueError(
-                    f"finish() before set_reply() for flow {fi} "
-                    f"({flows[fi].op_name})")
-        self._finished = True
-        if not replying:
-            return {}
+        with costs.scope("exchange.finish"):
+            if self._finished:
+                # callers must cache the returned dict; a second finish would
+                # launch a duplicate collective and double-record costs
+                raise ValueError("CommittedPlan already finished")
+            flows = self._plan._flows
+            replying = [fi for fi, f in enumerate(flows) if f.reply_lanes > 0]
+            for fi in replying:
+                if fi not in self._replies:
+                    raise ValueError(
+                        f"finish() before set_reply() for flow {fi} "
+                        f"({flows[fi].op_name})")
+            self._finished = True
+            if not replying:
+                return {}
 
-        if self._sequential:
-            # FINE oracle: each flow's reply is its own sub-plan finish,
-            # through the same transport as its request
+            if self._sequential:
+                # FINE oracle: each flow's reply is its own sub-plan finish,
+                # through the same transport as its request
+                outs = {}
+                for fi in replying:
+                    sub = self._subplans[fi]
+                    sub.set_reply(0, self._replies[fi])
+                    outs[fi] = sub.finish(backend)[0]
+                return outs
+
+            # owner replies in arrival order, masked to real arrivals; the
+            # transport lands them back in the requesters' send slots
+            staged = {fi: jnp.where(self._views[fi].valid[:, None],
+                                    self._replies[fi], 0)
+                      for fi in replying}
+            with costs.scope("transport.reply"):
+                slots = self._transport.reply(backend, self._tctx, staged)
+
             outs = {}
             for fi in replying:
-                sub = self._subplans[fi]
-                sub.set_reply(0, self._replies[fi])
-                outs[fi] = sub.finish(backend)[0]
+                f = flows[fi]
+                view = self._views[fi]
+                seg = slots[fi]
+                item = jnp.where(view.send_occ, view.send_item, f.n)
+                out = jnp.zeros((f.n, f.reply_lanes), _U32).at[item].set(
+                    seg, mode="drop")
+                answered = jnp.zeros((f.n,), bool).at[item].set(
+                    view.send_occ, mode="drop")
+                outs[fi] = (out, answered)
             return outs
-
-        # owner replies in arrival order, masked to real arrivals; the
-        # transport lands them back in the requesters' send slots
-        staged = {fi: jnp.where(self._views[fi].valid[:, None],
-                                self._replies[fi], 0)
-                  for fi in replying}
-        slots = self._transport.reply(backend, self._tctx, staged)
-
-        outs = {}
-        for fi in replying:
-            f = flows[fi]
-            view = self._views[fi]
-            seg = slots[fi]
-            item = jnp.where(view.send_occ, view.send_item, f.n)
-            out = jnp.zeros((f.n, f.reply_lanes), _U32).at[item].set(
-                seg, mode="drop")
-            answered = jnp.zeros((f.n,), bool).at[item].set(
-                view.send_occ, mode="drop")
-            outs[fi] = (out, answered)
-        return outs
 
 
 class PendingPlan:
@@ -848,22 +856,24 @@ class PendingPlan:
     def finish(self, backend: Backend) -> CommittedPlan:
         """Complete the wire; one-shot (a second wait would duplicate
         the transport's completion collectives and cost records)."""
-        if self._done:
-            raise ValueError("PendingPlan already finished")
-        self._done = True
-        if self._committed is not None:
-            return self._committed
-        st = self._staged
-        # the deferred launches' collectives/hops/bytes record exactly
-        # once, inside request_wait; the start's only extra observable
-        # is HOW MANY launches ran split-phase
-        costs.record(st.args.plan_op,
-                     costs.Cost(overlap_launches=self._handle.launched))
-        segments, extra_drop, tctx = self._transport.request_wait(
-            backend, self._handle)
-        return self._plan._finalize_fused(backend, st, segments,
-                                          extra_drop, tctx,
-                                          self._transport)
+        with costs.scope("exchange.commit"):
+            if self._done:
+                raise ValueError("PendingPlan already finished")
+            self._done = True
+            if self._committed is not None:
+                return self._committed
+            st = self._staged
+            # the deferred launches' collectives/hops/bytes record exactly
+            # once, inside request_wait; the start's only extra observable
+            # is HOW MANY launches ran split-phase
+            costs.record(st.args.plan_op,
+                         costs.Cost(overlap_launches=self._handle.launched))
+            with costs.scope("transport.request"):
+                segments, extra_drop, tctx = self._transport.request_wait(
+                    backend, self._handle)
+            return self._plan._finalize_fused(backend, st, segments,
+                                              extra_drop, tctx,
+                                              self._transport)
 
 
 class PendingResult:
@@ -993,33 +1003,36 @@ def reply(backend: Backend,
     so a non-dense transport raises here and the caller must reply
     through ``finish`` (declare ``reply_lanes`` on the flow).
     """
-    tr = make_transport(transport)
-    if tr.name != "dense":
-        raise ValueError(
-            f"reply({op_name!r}): the standalone reply is the dense "
-            f"inverse permutation; a flow routed over transport "
-            f"{tr.name!r} must declare reply_lanes and reply through "
-            f"CommittedPlan.finish, which holds the transport's inverse "
-            f"hop state")
-    if reply_payload.ndim == 1:
-        reply_payload = reply_payload[:, None]
-    lanes = reply_payload.shape[1]
+    with costs.scope("exchange.finish"):
+        tr = make_transport(transport)
+        if tr.name != "dense":
+            raise ValueError(
+                f"reply({op_name!r}): the standalone reply is the dense "
+                f"inverse permutation; a flow routed over transport "
+                f"{tr.name!r} must declare reply_lanes and reply through "
+                f"CommittedPlan.finish, which holds the transport's inverse "
+                f"hop state")
+        if reply_payload.ndim == 1:
+            reply_payload = reply_payload[:, None]
+        lanes = reply_payload.shape[1]
 
-    # ride the transport's inverse permutation (one single-flow wire):
-    # bit-identical to the pre-transport direct all-to-all, and keeps
-    # every physical collective inside core/transport.py
-    spec = FlowWire(req.capacity, 1, lanes + 1, lanes, orig_n, op_name)
-    staged = {0: jnp.where(req.valid[:, None],
-                           reply_payload.astype(_U32), 0)}
-    back = tr.reply(backend, _DenseCtx([spec], op_name, "auto"), staged)[0]
+        # ride the transport's inverse permutation (one single-flow wire):
+        # bit-identical to the pre-transport direct all-to-all, and keeps
+        # every physical collective inside core/transport.py
+        spec = FlowWire(req.capacity, 1, lanes + 1, lanes, orig_n, op_name)
+        staged = {0: jnp.where(req.valid[:, None],
+                               reply_payload.astype(_U32), 0)}
+        with costs.scope("transport.reply"):
+            back = tr.reply(backend, _DenseCtx([spec], op_name, "auto"),
+                            staged)[0]
 
-    # back[k] answers the item this rank placed in send slot k of the
-    # original route call
-    item = jnp.where(req.send_occ, req.send_item, orig_n)  # drop sentinel
-    out = jnp.zeros((orig_n, lanes), _U32).at[item].set(back, mode="drop")
-    answered = jnp.zeros((orig_n,), bool).at[item].set(
-        req.send_occ, mode="drop")
-    return out, answered
+        # back[k] answers the item this rank placed in send slot k of the
+        # original route call
+        item = jnp.where(req.send_occ, req.send_item, orig_n)  # drop sentinel
+        out = jnp.zeros((orig_n, lanes), _U32).at[item].set(back, mode="drop")
+        answered = jnp.zeros((orig_n,), bool).at[item].set(
+            req.send_occ, mode="drop")
+        return out, answered
 
 
 def suggest_rounds(loads, capacity: int, slack: float = 1.0,

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import costs
 from repro.kernels import pallas_call
 from repro.kernels.ref import MODE_SET, MODE_ADD, hash_probe_find_ref
 
@@ -58,19 +59,21 @@ def bin_queries(qblock, qvalid, nb: int, q_cap: int):
     Returns (bin_slot(M,) flat index into (nb, q_cap), overflow(M,) bool).
     Stable order within a block == original batch order.
     """
-    m = qblock.shape[0]
-    b = jnp.where(qvalid, qblock.astype(_I32), nb)
-    order = jnp.argsort(b, stable=True)
-    sortb = b[order]
-    # rank within the block = sorted position - the block's first sorted
-    # position (a binary search, not a prefix sum over all nb blocks)
-    first = jnp.searchsorted(sortb, sortb, side="left").astype(_I32)
-    pos = jnp.arange(m, dtype=_I32) - first
-    pos_orig = jnp.zeros((m,), _I32).at[order].set(pos)
-    overflow = qvalid & (pos_orig >= q_cap)
-    ok = qvalid & ~overflow
-    slot = jnp.where(ok, qblock.astype(_I32) * q_cap + pos_orig, nb * q_cap)
-    return slot, overflow
+    with costs.scope("probe.bin"):
+        m = qblock.shape[0]
+        b = jnp.where(qvalid, qblock.astype(_I32), nb)
+        order = jnp.argsort(b, stable=True)
+        sortb = b[order]
+        # rank within the block = sorted position - the block's first sorted
+        # position (a binary search, not a prefix sum over all nb blocks)
+        first = jnp.searchsorted(sortb, sortb, side="left").astype(_I32)
+        pos = jnp.arange(m, dtype=_I32) - first
+        pos_orig = jnp.zeros((m,), _I32).at[order].set(pos)
+        overflow = qvalid & (pos_orig >= q_cap)
+        ok = qvalid & ~overflow
+        slot = jnp.where(ok, qblock.astype(_I32) * q_cap + pos_orig,
+                         nb * q_cap)
+        return slot, overflow
 
 
 def _bin_rows(rows, slot, nb: int, q_cap: int):
@@ -79,16 +82,17 @@ def _bin_rows(rows, slot, nb: int, q_cap: int):
     Lane ``l`` of the query in bin slot ``b*q_cap + j`` lands in row
     ``b``, column ``l*q_cap + j``; rows with the drop slot vanish.
     """
-    lanes = rows.shape[1]
-    width = lanes * q_cap
-    blk, pos = slot // q_cap, slot % q_cap
-    # (L, M) lane-major indices: no (M, L) index array padded on TPU
-    col = jnp.arange(lanes, dtype=_I32)[:, None] * q_cap + pos[None, :]
-    idx = jnp.where((slot < nb * q_cap)[None, :], blk[None, :] * width + col,
-                    nb * width)
-    out = jnp.zeros((nb * width,), _U32).at[idx.reshape(-1)].set(
-        rows.astype(_U32).T.reshape(-1), mode="drop")
-    return out.reshape(nb, width)
+    with costs.scope("probe.bin"):
+        lanes = rows.shape[1]
+        width = lanes * q_cap
+        blk, pos = slot // q_cap, slot % q_cap
+        # (L, M) lane-major indices: no (M, L) index array padded on TPU
+        col = jnp.arange(lanes, dtype=_I32)[:, None] * q_cap + pos[None, :]
+        idx = jnp.where((slot < nb * q_cap)[None, :],
+                        blk[None, :] * width + col, nb * width)
+        out = jnp.zeros((nb * width,), _U32).at[idx.reshape(-1)].set(
+            rows.astype(_U32).T.reshape(-1), mode="drop")
+        return out.reshape(nb, width)
 
 
 def default_q_cap(m: int, nb: int) -> int:
@@ -245,19 +249,20 @@ def insert_binned(tkeys, tvals, status, qbins, mode: int, q_cap: int,
 def _insert_rows(tkeys, tvals, status, qblock, rows, valid, mode: int,
                  q_cap: int | None, tile_blocks: int | None):
     """Bin (M, Lk+Lv) key|value rows per block and run the insert kernel."""
-    nb = tkeys.shape[0]
-    m = qblock.shape[0]
-    q_cap = q_cap or default_q_cap(m, nb)
-    slot, overflow = bin_queries(qblock, valid, nb, q_cap)
-    comb = jnp.concatenate([rows.astype(_U32), valid.astype(_U32)[:, None]],
-                           axis=1)
-    otk, otv, ost, okbins = insert_binned(
-        tkeys, tvals, status, _bin_rows(comb, slot, nb, q_cap), mode, q_cap,
-        tile_blocks)
-    flat_ok = okbins.reshape(-1)
-    take = jnp.minimum(slot, nb * q_cap - 1)
-    success = jnp.where(slot < nb * q_cap, flat_ok[take] == 1, False)
-    return otk, otv, ost, success & ~overflow & valid
+    with costs.scope("probe.insert"):
+        nb = tkeys.shape[0]
+        m = qblock.shape[0]
+        q_cap = q_cap or default_q_cap(m, nb)
+        slot, overflow = bin_queries(qblock, valid, nb, q_cap)
+        comb = jnp.concatenate([rows.astype(_U32),
+                                valid.astype(_U32)[:, None]], axis=1)
+        otk, otv, ost, okbins = insert_binned(
+            tkeys, tvals, status, _bin_rows(comb, slot, nb, q_cap), mode,
+            q_cap, tile_blocks)
+        flat_ok = okbins.reshape(-1)
+        take = jnp.minimum(slot, nb * q_cap - 1)
+        success = jnp.where(slot < nb * q_cap, flat_ok[take] == 1, False)
+        return otk, otv, ost, success & ~overflow & valid
 
 
 def insert(tkeys, tvals, status, qblock, qkeys, qvals, qvalid,
@@ -358,37 +363,40 @@ def find_binned(tkeys, tvals, status, qbins, q_cap: int,
 def _find_keys(tkeys, tvals, status, qblock, qkeys, valid,
                q_cap: int | None, tile_blocks: int | None):
     """Bin (M, Lk) key rows per block and run the find kernel."""
-    nb = tkeys.shape[0]
-    lv = tvals.shape[1]
-    m = qblock.shape[0]
-    q_cap = q_cap or default_q_cap(m, nb)
-    slot, overflow = bin_queries(qblock, valid, nb, q_cap)
-    comb = jnp.concatenate([qkeys.astype(_U32),
-                            (valid & ~overflow).astype(_U32)[:, None]],
-                           axis=1)
-    res = find_binned(tkeys, tvals, status, _bin_rows(comb, slot, nb, q_cap),
-                      q_cap, tile_blocks)
+    with costs.scope("probe.find"):
+        nb = tkeys.shape[0]
+        lv = tvals.shape[1]
+        m = qblock.shape[0]
+        q_cap = q_cap or default_q_cap(m, nb)
+        slot, overflow = bin_queries(qblock, valid, nb, q_cap)
+        comb = jnp.concatenate([qkeys.astype(_U32),
+                                (valid & ~overflow).astype(_U32)[:, None]],
+                               axis=1)
+        res = find_binned(tkeys, tvals, status,
+                          _bin_rows(comb, slot, nb, q_cap), q_cap,
+                          tile_blocks)
 
-    in_range = slot < nb * q_cap
-    blk = jnp.minimum(slot // q_cap, nb - 1)
-    pos = slot % q_cap
-    cols = jnp.arange(lv + 1, dtype=_I32)[:, None] * q_cap + pos[None, :]
-    got = res.reshape(-1)[blk[None, :] * ((lv + 1) * q_cap) + cols]
-    found = in_range & (got[lv] == 1) & valid & ~overflow   # got: (Lv+1, M)
-    vals = jnp.where(found[:, None], got[:lv].T, 0)
+        in_range = slot < nb * q_cap
+        blk = jnp.minimum(slot // q_cap, nb - 1)
+        pos = slot % q_cap
+        cols = jnp.arange(lv + 1, dtype=_I32)[:, None] * q_cap + pos[None, :]
+        got = res.reshape(-1)[blk[None, :] * ((lv + 1) * q_cap) + cols]
+        # got: (Lv+1, M)
+        found = in_range & (got[lv] == 1) & valid & ~overflow
+        vals = jnp.where(found[:, None], got[:lv].T, 0)
 
-    # overflow queries take the direct jnp probe (rare, bounded); it runs
-    # only when some query overflowed its block's bin
-    def probe_overflow(_):
-        return hash_probe_find_ref(tkeys, tvals, status,
-                                   jnp.clip(qblock, 0, nb - 1), qkeys,
-                                   overflow)
+        # overflow queries take the direct jnp probe (rare, bounded); it runs
+        # only when some query overflowed its block's bin
+        def probe_overflow(_):
+            return hash_probe_find_ref(tkeys, tvals, status,
+                                       jnp.clip(qblock, 0, nb - 1), qkeys,
+                                       overflow)
 
-    def none(_):
-        return jnp.zeros_like(found), jnp.zeros_like(vals)
+        def none(_):
+            return jnp.zeros_like(found), jnp.zeros_like(vals)
 
-    f2, v2 = jax.lax.cond(overflow.any(), probe_overflow, none, None)
-    return found | f2, jnp.where(f2[:, None], v2, vals)
+        f2, v2 = jax.lax.cond(overflow.any(), probe_overflow, none, None)
+        return found | f2, jnp.where(f2[:, None], v2, vals)
 
 
 def find(tkeys, tvals, status, qblock, qkeys, qvalid,

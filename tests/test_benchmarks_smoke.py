@@ -42,15 +42,14 @@ def test_micro_fused_arms_smoke():
 
 def test_micro_wire_arms_smoke(capsys):
     """The --wire {scatter,fused} arms (DESIGN.md section 1.10): both
-    wires run every variant, rows follow the shared CSV schema with the
-    hbm_passes column filled, the fused arm reports strictly fewer
-    standalone scatter passes than the scatter arm, and the wire choice
-    never changes bytes, collectives, or rounds."""
+    wires run every variant, rows follow the shared CSV schema, and the
+    wire choice never changes bytes, collectives, rounds, or hops.  (The
+    fused wire's zero standalone scatters per commit are pinned in
+    tests/test_wire_format.py.)"""
     from benchmarks import micro_hashmap, micro_queue
     from benchmarks.util import HEADER
     ncols = len(HEADER.split(","))
     hcols = HEADER.split(",")
-    ip = hcols.index("hbm_passes")
     rs = micro_hashmap.run(smoke=True, wire="scatter")
     rf = micro_hashmap.run(smoke=True, wire="fused")
     rq = micro_queue.run(smoke=True, wire="fused")
@@ -66,14 +65,9 @@ def test_micro_wire_arms_smoke(capsys):
                  "hashmap_find_atomic", "hashmap_find",
                  "hashmap_find_2attempt"):
         s, f = by_name[base + "_scatter"], by_name[base + "_fused"]
-        # the structural win: fewer HBM scatter passes when fused
-        assert int(f[ip]) < int(s[ip]), base
-        # ...at identical collectives / bytes / rounds / hops
+        # identical collectives / bytes / rounds / hops on both wires
         for i in (2, 3, 4, 8):
             assert s[i] == f[i], (base, hcols[i], s[i], f[i])
-    for cols in by_name.values():
-        if cols[0].endswith(("_scatter", "_fused")):
-            assert cols[ip] != "", cols[0]        # column filled
 
 
 def test_micro_skew_arms_smoke(capsys):

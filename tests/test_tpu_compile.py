@@ -9,13 +9,21 @@ find step under ``jax.shard_map``, which runs the whole wrapped path.  A kernel 
 that does not fit the chip's memory, fails here instead of on the chip.
 Nothing runs: these tests say nothing about results or times.
 
+Two more programs, the 1-chip ISx step (push + drain) and the 1-chip
+speculative find, pin the library's layer scopes (``costs.scope``,
+DESIGN.md section 1.11) in the compiled HLO: every op the library
+traces carries a ``bcl.`` scope, and the program compiled with the
+scopes is the program compiled without them, op for op.
+
 The topology is described inside a fixture, never at import, so every
 pytest worker collects the same tests and only the worker that runs this
 file loads the TPU library.  Kernel decisions are steered to the TPU by
 patching ``repro.kernels.platform`` inside each test.
 """
 
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 import repro.kernels as kernels
+from repro.core import costs
 from repro.kernels import binning, bloom_kernel, hash_probe, ops
 
 U32, I32, BOOL = jnp.uint32, jnp.int32, jnp.bool_
@@ -151,3 +160,173 @@ def test_hashmap_step_compiles_under_shard_map(topo, on_tpu):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < 16 * 10 ** 9, f"{used} bytes on a 16 GB chip"
+
+
+# --------------------------------------------------------------------------
+# layer scopes
+# --------------------------------------------------------------------------
+
+#: every scope the library opens (``costs.scope``), layer by layer
+SCOPES = {
+    "bcl.hashmap.create", "bcl.hashmap.insert", "bcl.hashmap.find",
+    "bcl.hashmap.find_insert", "bcl.queue.create", "bcl.queue.push",
+    "bcl.queue.pop", "bcl.queue.push_pop", "bcl.queue.drain",
+    "bcl.bloom.insert", "bcl.bloom.find", "bcl.bloom.insert_find",
+    "bcl.exchange.bin", "bcl.exchange.commit", "bcl.exchange.finish",
+    "bcl.transport.request", "bcl.transport.reply",
+    "bcl.probe.bin", "bcl.probe.find", "bcl.probe.insert",
+}
+#: the ops a device trace shows as work
+TRACED_KINDS = {"fusion", "custom-call", "gather", "scatter", "sort",
+                "all-to-all"}
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(?:\([^=]*?\)|\S+)"
+                    r"\s+([\w\-]+)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SCOPE = re.compile(r"(?:^|[/(])(bcl\.[\w.\-]+)")
+_FRAME_TABLES = {"FileNames", "FunctionNames", "FileLocations",
+                 "StackFrames"}
+ISX_KEYS = 1 << 18       # keys of one ISx step at test size
+FIND_SLOTS, FIND_N = 1 << 20, 1 << 16
+
+
+def _isx_step(mesh):
+    """One ISx iteration's library part: push every key to its bucket's
+    queue, drain the chip's own queue (``bench/configs/isx.py``)."""
+    from repro.containers import queue as q
+    from repro.core import get_backend
+
+    def step(keys, dest):
+        bk = get_backend("bcl")
+        spec, st = q.queue_create(bk, ISX_KEYS, SDS((), U32))
+        st, _, dropped = q.push(bk, spec, st, keys, dest,
+                                capacity=ISX_KEYS)
+        rows, got = q.local_drain(spec, st)
+        return rows, got, dropped[None]
+
+    bcl = P("bcl")
+    return (jax.shard_map(step, mesh=mesh, in_specs=bcl,
+                          out_specs=(bcl,) * 3),
+            [((ISX_KEYS,), U32), ((ISX_KEYS,), I32)])
+
+
+def _speculative_find(mesh):
+    """The lookup program of ``bench/configs/kmer_hashmap.py``: a
+    2-attempt find, both attempts as flows of one plan."""
+    from repro.containers import hashmap as hm
+    from repro.core import get_backend
+
+    def find(tk, tv, st, keys):
+        bk = get_backend("bcl")
+        spec, _ = hm.hashmap_create(bk, FIND_SLOTS, LK, LV,
+                                    block_size=BLOCK)
+        _, vals, found = hm.find(bk, spec, hm.HashMapState(tk, tv, st),
+                                 keys, capacity=FIND_N)
+        return vals, found
+
+    nb = FIND_SLOTS // BLOCK
+    shapes = [((nb, LK, BLOCK), U32), ((nb, LV, BLOCK), U32),
+              ((nb, BLOCK), U32), ((FIND_N, LK), U32)]
+    return (jax.shard_map(find, mesh=mesh, in_specs=(P("bcl"),) * 4,
+                          out_specs=(P("bcl"),) * 2), shapes)
+
+
+SCOPE_PROGRAMS = {"isx_step": _isx_step, "speculative_find": _speculative_find}
+
+
+@pytest.fixture(scope="module")
+def compiled_hlo(topo):
+    """``hlo(name, scoped=True)``: the compiled HLO text of one program,
+    with the library's scopes or with ``costs.scope`` a null context,
+    compiled once for this module (inside a test, under ``on_tpu``)."""
+    texts = {}
+
+    def hlo(name, scoped=True):
+        if (name, scoped) not in texts:
+            mesh = Mesh(np.array(topo.devices[:1]), ("bcl",))
+            sharded = NamedSharding(mesh, P("bcl"))
+            real = costs.scope
+            if not scoped:
+                costs.scope = lambda op: contextlib.nullcontext()
+            try:
+                fn, shapes = SCOPE_PROGRAMS[name](mesh)
+                args = [SDS(s, d, sharding=sharded) for s, d in shapes]
+                texts[name, scoped] = (jax.jit(fn).lower(*args).compile()
+                                       .as_text())
+            finally:
+                costs.scope = real
+        return texts[name, scoped]
+    return hlo
+
+
+def _innermost_scope(op_name: str):
+    found = _SCOPE.findall(op_name)
+    return found[-1] if found else None
+
+
+def _ops(text):
+    """(instruction, opcode, op_name or None) of every instruction."""
+    out = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            src = _OP_NAME.search(line)
+            out.append((m.group(1), m.group(2),
+                        src.group(1) if src else None))
+    return out
+
+
+def _without_names(text):
+    """The module less its metadata: no ``metadata={...}``, no stack
+    frame tables, and instructions renamed in order of appearance (XLA
+    names a Pallas call after the innermost name-stack entry)."""
+    kept, skip = [], False
+    for line in text.splitlines():
+        if line in _FRAME_TABLES:
+            skip = True
+        elif skip and not line:
+            skip = False
+        elif not skip:
+            kept.append(re.sub(r",? metadata=\{[^}]*\}", "", line))
+    names = {}
+    rename = lambda m: "%" + names.setdefault(m.group(1), f"v{len(names)}")
+    return [re.sub(r"%([\w.\-]+)", rename, line) for line in kept]
+
+
+@pytest.mark.parametrize("name", sorted(SCOPE_PROGRAMS))
+def test_library_ops_carry_a_layer_scope(name, compiled_hlo, on_tpu):
+    """Every traced op the library emits (its ``op_name`` starts at the
+    jitted program, ``jit(...)``) names a scope of the vocabulary; the
+    ISx gathers and scatters land in the transport and the queue, the
+    lookup's binning in the owner probe."""
+    text = compiled_hlo(name)
+    seen, missing = {}, []
+    for instr, opcode, op_name in _ops(text):
+        if opcode not in TRACED_KINDS or not (op_name or "").startswith(
+                "jit("):
+            continue
+        scope = _innermost_scope(op_name)
+        if scope is None:
+            missing.append(f"{instr} ({opcode}): {op_name}")
+        seen.setdefault(opcode, set()).add(scope)
+    assert not missing, "ops with no layer scope:\n" + "\n".join(missing)
+    assert set().union(*seen.values()) <= SCOPES
+    if name == "isx_step":
+        assert seen["gather"] == {"bcl.transport.request",
+                                  "bcl.queue.drain"}
+        assert "bcl.queue.push" in seen["scatter"]
+        assert "bcl.exchange.bin" in seen["custom-call"]
+    else:
+        assert {"bcl.probe.bin", "bcl.probe.find"} <= seen["gather"]
+        assert "bcl.probe.find" in seen["custom-call"]
+        assert "bcl.probe.bin" in seen["sort"]
+        assert "bcl.transport.reply" in seen["fusion"]
+
+
+@pytest.mark.parametrize("name", sorted(SCOPE_PROGRAMS))
+def test_scopes_change_no_op(name, compiled_hlo, on_tpu):
+    """With its metadata and instruction names set aside, the program
+    compiled with the scopes is the program compiled without them."""
+    scoped = _without_names(compiled_hlo(name))
+    plain = _without_names(compiled_hlo(name, scoped=False))
+    assert len(scoped) > 100
+    assert scoped == plain
