@@ -86,9 +86,11 @@ def ragged_offsets(widths) -> tuple[list[int], int]:
     analogue of this module's lane matrices, with no cross-flow padding.
     Returns ``(starts, total)`` where ``starts[f]`` is the first word of
     segment ``f`` and ``total`` is the words per destination block.
-    Packing goes through :func:`scatter_rows`; unpacking is free — a
-    segment's rows are contiguous, so every owner view is a slice plus
-    reshape, never a gather.
+    Within a segment the rows are lane-planar: lane ``k`` of rows
+    ``[0, C_f)`` fills words ``[k*C_f, (k+1)*C_f)``.  Packing goes
+    through :func:`scatter_rows` with lane stride ``C_f``; unpacking is
+    :func:`rows_from_words`, a reshape that moves only major dimensions,
+    never a gather.
     """
     starts, off = [], 0
     for w in widths:
@@ -98,14 +100,16 @@ def ragged_offsets(widths) -> tuple[list[int], int]:
 
 
 def scatter_rows(flat: jax.Array, base: jax.Array, rows: jax.Array,
-                 widths: jax.Array | None = None) -> jax.Array:
-    """Pack (N, W) u32 rows into a flat word buffer at per-row offsets.
+                 stride=1, widths: jax.Array | None = None) -> jax.Array:
+    """Pack (N, W) u32 rows into a flat word buffer, one lane per plane.
 
-    Row ``i`` lands at words ``[base[i], base[i] + W)``; a sentinel
-    ``base[i] >= flat.size`` drops the row.  This is the ragged wire's
-    serializer and the declared fallback/oracle for the fused Pallas
-    wire (``kernels/ops.pack_rows`` — DESIGN.md section 1.10): the hot
-    path packs in-kernel, this XLA scatter stays as the jnp reference.
+    Lane ``k`` of row ``i`` lands at word ``base[i] + k * stride``, with
+    ``stride`` one int or one per row (the lane stride of the row's
+    segment, its capacity on the wire); a sentinel ``base[i] >=
+    flat.size`` drops the row.  This is the ragged wire's serializer and
+    the declared fallback/oracle for the fused Pallas wire
+    (``kernels/ops.pack_rows`` — DESIGN.md section 1.10): the hot path
+    packs in-kernel, this XLA scatter stays as the jnp reference.
 
     With ``widths`` (per-row word counts <= W), lanes past ``widths[i]``
     are dropped — one rectangular call packs right-padded rows of mixed
@@ -114,30 +118,39 @@ def scatter_rows(flat: jax.Array, base: jax.Array, rows: jax.Array,
     # word indices are built lane-major, (W, N): with the N rows on the
     # minor axis no index or update array is padded out to the vector
     # width on TPU, as an (N, W) one with a few lanes would be
-    w = rows.shape[1]
-    lane = jnp.arange(w, dtype=base.dtype)[:, None]
-    idx = base[None, :] + lane
+    size = flat.shape[0]
+    lane = jnp.arange(rows.shape[1], dtype=base.dtype)[:, None]
+    keep = base[None, :] < size
     if widths is not None:
-        idx = jnp.where(lane < widths[None, :].astype(base.dtype), idx,
-                        flat.shape[0])
+        keep = keep & (lane < widths[None, :].astype(base.dtype))
+    step = jnp.reshape(jnp.asarray(stride, base.dtype), (1, -1))
+    idx = jnp.where(keep, base[None, :] + lane * step, size)
     return flat.at[idx.reshape(-1)].set(rows.astype(_U32).T.reshape(-1),
                                         mode="drop")
 
 
 def rows_from_words(words: jax.Array, roww: int) -> jax.Array:
-    """View a run of ``roww``-word rows as an (N, roww) u32 matrix.
+    """View lane-planar segments as an (N, roww) u32 matrix.
 
-    The inverse of :func:`scatter_rows` over dense slots: ``words`` (any
-    shape, flattened row-major) holds whole rows back to back.  The rows
-    are gathered lane-major, (roww, N), and transposed, so on TPU they
-    need not take a row-major (N, roww) layout, which a reshape would
-    force and which pads a few lanes out to the 128-lane vector.
+    The inverse of :func:`scatter_rows` over dense slots: along its last
+    axis ``words`` holds ``roww`` planes of ``C`` words each, lane ``k``
+    of rows ``[0, C)`` in plane ``k``; the leading axes (if any) are
+    blocks of such segments.  Row ``b*C + j`` of the result is row ``j``
+    of block ``b``.  Only major dimensions move — ``(B, roww, C) ->
+    (roww, B*C)`` and the final transpose — so on TPU this is a layout
+    copy at most, never a gather, and no row-major ``(N, roww)`` array
+    with a few lanes (padded to the 128-lane vector) need be formed.
     """
-    flat = words.reshape(-1)
-    n = flat.shape[0] // roww
-    idx = (jnp.arange(n, dtype=jnp.int32)[None, :] * roww
-           + jnp.arange(roww, dtype=jnp.int32)[:, None])
-    return flat[idx].T
+    planes = words.reshape(-1, roww, words.shape[-1] // roww)
+    return jnp.swapaxes(planes, 0, 1).reshape(roww, -1).T
+
+
+def words_from_rows(rows: jax.Array, blocks: int) -> jax.Array:
+    """Lay (blocks*C, roww) rows out as ``blocks`` lane-planar segments,
+    ``(blocks, roww*C)``: the inverse of :func:`rows_from_words`."""
+    roww = rows.shape[1]
+    planes = rows.T.reshape(roww, blocks, -1)
+    return jnp.swapaxes(planes, 0, 1).reshape(blocks, -1)
 
 
 class Packer(abc.ABC):

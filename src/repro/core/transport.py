@@ -47,6 +47,11 @@ the axis needs (:func:`hop_shift`), leaving ``s = 32 - bits(P - 1)``
 bits for ``o``: more than any bucket rank of a wire whose ``P * R*C_f``
 word slots fit the i32 indices.
 
+Every flow segment on every hop, request and reply, is lane-planar
+(DESIGN.md §1.5): lane ``k`` of the row in slot ``o`` of a segment of
+capacity ``C`` is word ``k*C + o``, so each read-back
+(``rows_from_words``) is a reshape, never a gather.
+
 Cost attribution (DESIGN.md §1.7): the hop that touches the requester
 is charged under the flow's own ``op_name`` (request ``bytes_out``,
 reply ``bytes_in``); the hop between relay and owner is charged under
@@ -69,7 +74,8 @@ import jax.numpy as jnp
 
 from repro.core import costs
 from repro.core.backend import Backend
-from repro.core.object_container import ragged_offsets, rows_from_words
+from repro.core.object_container import (ragged_offsets, rows_from_words,
+                                         words_from_rows)
 from repro.kernels import ops as kops
 
 _U32 = jnp.uint32
@@ -260,8 +266,9 @@ class DenseTransport(Transport):
         for fi, s in enumerate(specs):
             # rounds concatenate per source: owner row s*(R*C_f) + o holds
             # the rank-o arrival from rank s, exactly the single-round
-            # layout at capacity R*C_f; the flow's word segment reads
-            # back as its own (rows, L_f+1) rows
+            # layout at capacity R*C_f; the flow's lane-planar word
+            # segments, (P, R, L_f+1, C_f), read back as its own rows
+            # by a reshape
             parts = [recvs[r][:, woffs_by_round[r][fi]:
                               woffs_by_round[r][fi] + s.capacity * s.roww]
                      for r in range(s.rounds)]
@@ -304,12 +311,13 @@ class DenseTransport(Transport):
         for fi in replying:
             cap = specs[fi].cap_e
             rl = rls[fi]
-            # owner arrival row s*C_f + j  ->  words
-            # [s*wtot + seg_f + j*R_f, ... + R_f) — the flow's own ragged
-            # segment, exactly R_f words per reply
+            # lane k of owner arrival row s*C_f + j  ->  word
+            # s*wtot + seg_f + k*C_f + j — the flow's own ragged segment,
+            # exactly R_f words per reply, lane-planar
             ar = jnp.arange(nprocs * cap, dtype=_I32)
-            base = (ar // cap) * wtot + seg_off[fi] + (ar % cap) * rl
-            send = kops.place_rows(send, base, staged[fi], impl=ctx.impl)
+            base = (ar // cap) * wtot + seg_off[fi] + ar % cap
+            send = kops.place_rows(send, base, staged[fi], cap,
+                                   impl=ctx.impl)
 
         back2 = backend.all_to_all(send).reshape(nprocs, wtot)
 
@@ -651,12 +659,15 @@ class HierarchicalTransport(Transport):
         extra = jnp.zeros((nflows,), _I32)
         for out in rounds:
             for fi, (dslot, rows) in out.scatters.items():
-                # dense-slot owner scatter through the in-kernel placer:
-                # word slot = row slot * row width, sentinel rows (dslot
-                # == P * cap_e) land exactly at the buffer size and drop
+                # dense-slot owner scatter through the in-kernel placer,
+                # lane-planar over all P * cap_e rows: lane k of row
+                # dslot at word k*P*cap_e + dslot; sentinel rows (dslot
+                # == P * cap_e) move to the buffer size and drop
+                rows_e = nprocs * specs[fi].cap_e
                 seg_out[fi] = kops.place_rows(
-                    seg_out[fi], dslot * specs[fi].roww, rows,
-                    impl=args.impl)
+                    seg_out[fi],
+                    jnp.where(dslot < rows_e, dslot, seg_out[fi].shape[0]),
+                    rows, rows_e, impl=args.impl)
             extra = extra + out.extra
         seg_out = [rows_from_words(w, s.roww) for w, s in zip(seg_out, specs)]
 
@@ -731,7 +742,7 @@ class HierarchicalTransport(Transport):
                 rows = jnp.where(
                     in_r[:, None],
                     staged[fi][jnp.minimum(dslot, nprocs * s.cap_e - 1)], 0)
-                parts.append(rows.reshape(pr, c2[fi] * rls[fi]))
+                parts.append(words_from_rows(rows, pr))
             layout.append(rf)
             blocks2.append(jnp.concatenate(parts, axis=1) if parts
                            else jnp.zeros((pr, 0), _U32))
@@ -755,7 +766,7 @@ class HierarchicalTransport(Transport):
                 rows = jnp.where(
                     in_r[:, None],
                     rep2[jnp.minimum(r2, pr * c2[fi] - 1)], 0)
-                parts.append(rows.reshape(pc, c1[fi] * rl))
+                parts.append(words_from_rows(rows, pc))
             blocks1.append(jnp.concatenate(parts, axis=1) if parts
                            else jnp.zeros((pc, 0), _U32))
         send1 = jnp.concatenate(blocks1, axis=1)
@@ -778,8 +789,13 @@ class HierarchicalTransport(Transport):
                 rows = jnp.where(
                     in_r[:, None],
                     rep1[jnp.minimum(r1, pc * c1[fi] - 1)], 0)
-                outs[fi] = kops.place_rows(outs[fi], dslot * rl, rows,
-                                           impl=ctx.impl)
+                # lane-planar over the P * cap_e send slots, as the
+                # owner assembly; sentinel slots move past the buffer
+                rows_e = nprocs * specs[fi].cap_e
+                outs[fi] = kops.place_rows(
+                    outs[fi],
+                    jnp.where(dslot < rows_e, dslot, outs[fi].shape[0]),
+                    rows, rows_e, impl=ctx.impl)
 
         outs = {fi: rows_from_words(w, rls[fi]) for fi, w in outs.items()}
         for fi in sorted(staged):

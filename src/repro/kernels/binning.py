@@ -122,14 +122,14 @@ def bin_offsets(bins: jax.Array, nbins: int, valid: jax.Array | None = None,
     return counts, offs[:m]
 
 
-def _ragged_slots_kernel(woff_ref, roww_ref, caps_ref, rounds_ref,
+def _ragged_slots_kernel(woff_ref, caps_ref, rounds_ref,
                          bins_ref, flow_ref, off_ref, valid_ref,
                          slot_ref, *, nflows: int, rnd: int, wtot: int,
                          sentinel: int):
-    """Per-item ragged word slot off the ONE binning pass.
+    """Per-item ragged lane-0 word slot off the ONE binning pass.
 
     Items are a lane-dense (rows, 128) tile; the flow tables (word
-    offset, row words, capacity, rounds) sit in SMEM and each item picks
+    offset, capacity, rounds) sit in SMEM and each item picks
     its flow's entries by a select per flow (nflows is tiny).  Then the
     retry-round window ``[rnd*C_f, (rnd+1)*C_f)`` masks which items ride
     this launch — the §1.6 mask and the §1.5 ragged layout fused into
@@ -139,32 +139,31 @@ def _ragged_slots_kernel(woff_ref, roww_ref, caps_ref, rounds_ref,
     flow = flow_ref[...]
     off = off_ref[...]
     valid = valid_ref[...] != 0
-    woff_i = roww_i = cap_i = rnds_i = jnp.zeros_like(bins)
+    woff_i = cap_i = rnds_i = jnp.zeros_like(bins)
     for f in range(nflows):
         at = flow == f
         woff_i = jnp.where(at, woff_ref[f], woff_i)
-        roww_i = jnp.where(at, roww_ref[f], roww_i)
         cap_i = jnp.where(at, caps_ref[f], cap_i)
         rnds_i = jnp.where(at, rounds_ref[f], rnds_i)
     off_r = off - rnd * cap_i
     in_r = valid & (rnds_i > rnd) & (off_r >= 0) & (off_r < cap_i)
-    slot_ref[...] = jnp.where(in_r, bins * wtot + woff_i + off_r * roww_i,
-                              sentinel)
+    slot_ref[...] = jnp.where(in_r, bins * wtot + woff_i + off_r, sentinel)
 
 
 def ragged_slots(bins: jax.Array, flow: jax.Array, offsets: jax.Array,
                  valid: jax.Array, rnd: int, word_off: jax.Array,
-                 row_words: jax.Array, caps: jax.Array, rounds: jax.Array,
-                 wtot: int, sentinel: int, tile: int = 2048) -> jax.Array:
-    """Ragged send-buffer word slots for retry round ``rnd``.
+                 caps: jax.Array, rounds: jax.Array, wtot: int,
+                 sentinel: int, tile: int = 2048) -> jax.Array:
+    """Ragged send-buffer lane-0 word slots for retry round ``rnd``.
 
     Item ``i`` of flow ``f = flow[i]`` with within-(dest, flow)-bucket
-    rank ``offsets[i]`` (from :func:`bin_offsets`) starts at word
-    ``bins[i]*wtot + word_off[f] + (offsets[i] - rnd*caps[f]) *
-    row_words[f]`` of the flat fused wire iff its rank falls in round
-    ``rnd``'s capacity window and the flow is still retrying; every
-    other item gets ``sentinel`` (a drop index past the buffer).
-    Oracle: the pure-jnp gather in ``kernels/ops.py::ragged_slots``.
+    rank ``offsets[i]`` (from :func:`bin_offsets`) has its lane 0 at
+    word ``bins[i]*wtot + word_off[f] + (offsets[i] - rnd*caps[f])`` of
+    the flat fused wire (lane ``k`` is ``k*caps[f]`` words on) iff its
+    rank falls in round ``rnd``'s capacity window and the flow is still
+    retrying; every other item gets ``sentinel`` (a drop index past the
+    buffer).  Oracle: the pure-jnp gather in
+    ``kernels/ops.py::ragged_slots``.
     """
     m = bins.shape[0]
     nflows = word_off.shape[0]
@@ -181,12 +180,11 @@ def ragged_slots(bins: jax.Array, flow: jax.Array, offsets: jax.Array,
     slots = pallas_call(
         kern,
         grid=(rows * _LANES // tile,),
-        in_specs=[table] * 4 + [items] * 4,
+        in_specs=[table] * 3 + [items] * 4,
         out_specs=items,
         out_shape=jax.ShapeDtypeStruct((rows, _LANES), _I32),
-    )(word_off.astype(_I32), row_words.astype(_I32), caps.astype(_I32),
-      rounds.astype(_I32), dense(bins), dense(flow), dense(offsets),
-      dense(valid))
+    )(word_off.astype(_I32), caps.astype(_I32), rounds.astype(_I32),
+      dense(bins), dense(flow), dense(offsets), dense(valid))
     return slots.reshape(-1)[:m]
 
 
@@ -199,7 +197,8 @@ def _pack_rows_kernel(rows_ref, bins_ref, flow_ref, off_ref, valid_ref,
     Same slot math as :func:`_ragged_slots_kernel`, but instead of
     emitting the slot vector for an XLA ``.at[].set`` to consume (one
     extra HBM round trip over the rows), each tile scatters its rows
-    straight into the flat send buffer held in the output block.  All
+    straight into the flat send buffer held in the output block, lane
+    ``k`` of a row ``k * caps[f]`` words past its slot.  All
     grid steps map the same (total,) block; step 0 zero-fills.  Lanes at
     or past a row's flow width, rows outside the round window, and
     sentinel rows all index past ``total`` and drop.
@@ -225,10 +224,10 @@ def _pack_rows_kernel(rows_ref, bins_ref, flow_ref, off_ref, valid_ref,
     cap_i, rnds_i = sel(caps_ref), sel(rounds_ref)
     off_r = off - rnd * cap_i
     in_r = valid & (rnds_i > rnd) & (off_r >= 0) & (off_r < cap_i)
-    slot = jnp.where(in_r, bins * wtot + woff_i + off_r * roww_i, total)
+    slot = jnp.where(in_r, bins * wtot + woff_i + off_r, total)
     lane = jax.lax.broadcasted_iota(_I32, (tm, wmax), 1)
     idx = jnp.where((lane < roww_i[:, None]) & in_r[:, None],
-                    slot[:, None] + lane, total)
+                    slot[:, None] + lane * cap_i[:, None], total)
     buf = out_ref[...]
     out_ref[...] = buf.at[idx.reshape(-1)].set(
         rows_ref[...].astype(_U32).reshape(-1), mode="drop")
@@ -280,11 +279,12 @@ def pack_rows(rows: jax.Array, bins: jax.Array, flow: jax.Array,
 
 
 def _place_rows_kernel(dst_ref, slot_ref, rows_ref, out_ref, *,
-                       total: int, w: int):
+                       total: int, w: int, stride: int):
     """Scatter fixed-width rows at precomputed word slots, in-kernel.
 
     The output block starts as a copy of ``dst`` (step 0) and each tile
-    folds its rows in; a slot at or past ``total`` drops its row.  Used
+    folds its rows in, lane ``k`` of a row ``k * stride`` words past its
+    slot; a slot at or past ``total`` drops its row.  Used
     where the wire slots are analytic (dense replies, owner-side
     assembly by hop/slot lane) so even those writes stay off XLA's
     scatter path.
@@ -298,17 +298,19 @@ def _place_rows_kernel(dst_ref, slot_ref, rows_ref, out_ref, *,
     slot = slot_ref[...].astype(_I32)
     tm = slot.shape[0]
     lane = jax.lax.broadcasted_iota(_I32, (tm, w), 1)
-    idx = jnp.where(slot[:, None] < total, slot[:, None] + lane, total)
+    idx = jnp.where(slot[:, None] < total, slot[:, None] + lane * stride,
+                    total)
     buf = out_ref[...]
     out_ref[...] = buf.at[idx.reshape(-1)].set(
         rows_ref[...].astype(_U32).reshape(-1), mode="drop")
 
 
 def place_rows(dst: jax.Array, slots: jax.Array, rows: jax.Array,
-               tile: int = 2048) -> jax.Array:
+               stride: int = 1, tile: int = 2048) -> jax.Array:
     """In-kernel ``scatter_rows``: pack (N, W) rows into ``dst`` words.
 
-    Bit-identical to ``object_container.scatter_rows(dst, slots, rows)``
+    Bit-identical to ``object_container.scatter_rows(dst, slots, rows,
+    stride=stride)``
     (the jnp oracle) including the drop-on-sentinel contract; rows whose
     slot is ``>= dst.size`` are dropped whole.
     """
@@ -319,7 +321,8 @@ def place_rows(dst: jax.Array, slots: jax.Array, rows: jax.Array,
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
         slots = jnp.pad(slots, (0, pad), constant_values=total)
     mp = slots.shape[0]
-    kern = functools.partial(_place_rows_kernel, total=total, w=w)
+    kern = functools.partial(_place_rows_kernel, total=total, w=w,
+                             stride=stride)
     full = lambda i: (0,)
     return pallas_call(
         kern,

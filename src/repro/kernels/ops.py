@@ -374,29 +374,29 @@ def ragged_slots(bins, flow, offsets, valid, rnd: int, word_off, row_words,
 
     The ExchangePlan send buffer is a flat u32 word vector per
     destination (DESIGN.md section 1.5): flow ``f``'s segment starts at
-    ``word_off[f]`` and its rows are exactly ``row_words[f] = L_f + 1``
-    words wide — no cross-flow padding.  This op turns the ONE
-    :func:`multi_bin_offsets` pass's within-bucket ranks into per-item
-    word slots for one launch: item i starts at ``bins[i]*wtot +
-    word_off[flow[i]] + (offsets[i] - rnd*caps[flow[i]]) *
-    row_words[flow[i]]`` iff its rank falls in round ``rnd``'s capacity
+    ``word_off[f]`` and holds ``caps[f]`` rows of exactly ``row_words[f]
+    = L_f + 1`` words — no cross-flow padding — lane-planar: lane ``k``
+    of the row of in-round rank ``o`` is word ``k*caps[f] + o`` of the
+    segment.  This op turns the ONE :func:`multi_bin_offsets` pass's
+    within-bucket ranks into each item's lane-0 word for one launch:
+    ``bins[i]*wtot + word_off[flow[i]] + (offsets[i] -
+    rnd*caps[flow[i]])`` iff its rank falls in round ``rnd``'s capacity
     window ``[rnd*C_f, (rnd+1)*C_f)`` and ``rounds[flow[i]] > rnd``;
     all other items get ``sentinel`` (an index past the buffer, dropped
-    by the scatter).  Retry rounds therefore reuse the same offsets
-    with a different ``rnd`` — never a second binning pass.
+    by the scatter).  The slot does not depend on ``row_words``: the
+    packer steps lanes by ``caps[f]`` (:func:`pack_rows`).  Retry rounds
+    reuse the same offsets with a different ``rnd`` — never a second
+    binning pass.
     """
     impl = _resolve(impl)
     if impl == "pallas":
         from repro.kernels import binning
         return binning.ragged_slots(bins, flow, offsets, valid, rnd,
-                                    word_off, row_words, caps, rounds,
-                                    wtot, sentinel)
+                                    word_off, caps, rounds, wtot, sentinel)
     f = flow.astype(_I32)
     off_r = offsets.astype(_I32) - rnd * caps[f]
     in_r = valid & (rounds[f] > rnd) & (off_r >= 0) & (off_r < caps[f])
-    return jnp.where(in_r,
-                     bins.astype(_I32) * wtot + word_off[f]
-                     + off_r * row_words[f],
+    return jnp.where(in_r, bins.astype(_I32) * wtot + word_off[f] + off_r,
                      sentinel).astype(_I32)
 
 
@@ -408,8 +408,9 @@ def stage_slots(bins, flow, offsets, valid, word_off, row_words, caps,
     *column* at the source, by destination *row* at the relay — and
     packs each hop's wire with the same ragged offset-table math as the
     fused plan wire.  This is :func:`ragged_slots` with no retry-round
-    window: item i of flow ``f`` gets word ``bins[i]*wtot + word_off[f]
-    + offsets[i]*row_words[f]`` iff it is valid, its stage rank is
+    window: item i of flow ``f`` gets lane-0 word ``bins[i]*wtot +
+    word_off[f] + offsets[i]`` (lanes a stage capacity apart) iff it is
+    valid, its stage rank is
     below the stage capacity ``caps[f]``, and ``live[f]`` marks the
     flow as riding this hop; everything else gets ``sentinel``.  Both
     the jnp path and the Pallas kernel are the existing ``ragged_slots``
@@ -427,8 +428,9 @@ def pack_rows(rows, bins, flow, offsets, valid, rnd: int, word_off,
 
     ``rows`` is the (N, wmax) right-padded u32 row matrix over all flows
     in item order (flow ``f`` uses lanes ``[0, row_words[f])``); returns
-    the flat ``(total,)`` u32 send buffer for retry round ``rnd``.  The
-    jnp path is the declared fallback/oracle — :func:`ragged_slots`
+    the flat ``(total,)`` u32 send buffer for retry round ``rnd``, each
+    flow's segment lane-planar (lane ``k`` of an item ``k * caps[f]``
+    words past its :func:`ragged_slots` word).  The jnp path is the declared fallback/oracle — :func:`ragged_slots`
     followed by ``object_container.scatter_rows`` (the two-pass XLA
     lowering, DESIGN.md section 1.10); off the TPU the Pallas path
     writes the wire exactly once (``kernels/binning.pack_rows``), and on
@@ -443,23 +445,27 @@ def pack_rows(rows, bins, flow, offsets, valid, rnd: int, word_off,
     from repro.core.object_container import scatter_rows
     slots = ragged_slots(bins, flow, offsets, valid, rnd, word_off,
                          row_words, caps, rounds, wtot, total, impl=impl)
+    f = flow.astype(_I32)
     return scatter_rows(jnp.zeros((total,), _U32), slots, rows,
-                        widths=row_words[flow.astype(_I32)])
+                        stride=caps[f], widths=row_words[f])
 
 
-def place_rows(dst, slots, rows, impl: str = "auto"):
-    """Scatter fixed-width (N, W) rows into ``dst`` at word ``slots``.
+def place_rows(dst, slots, rows, stride: int = 1, impl: str = "auto"):
+    """Scatter fixed-width (N, W) rows into ``dst``: lane ``k`` of row
+    ``i`` at word ``slots[i] + k * stride``.
 
-    Rows with ``slots[i] >= dst.size`` drop.  jnp path is
+    The wire's callers pass their segment's capacity as ``stride``
+    (lane-planar, DESIGN.md section 1.5).  Rows with ``slots[i] >=
+    dst.size`` drop.  jnp path is
     ``object_container.scatter_rows`` (the fallback/oracle); off the
     TPU the Pallas path folds the scatter into one kernel pass, and on
     TPU it is that XLA scatter (:data:`XLA_WIRE_OPS`).
     """
     if _wire_kernel(_resolve(impl)):
         from repro.kernels import binning
-        return binning.place_rows(dst, slots, rows)
+        return binning.place_rows(dst, slots, rows, stride)
     from repro.core.object_container import scatter_rows
-    return scatter_rows(dst, slots, rows)
+    return scatter_rows(dst, slots, rows, stride=stride)
 
 
 # --------------------------------------------------------------------------

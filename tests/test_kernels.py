@@ -231,6 +231,69 @@ class TestBinning:
         assert np.array_equal(np.asarray(oj)[np.asarray(base)[~kept]],
                               np.asarray(rows)[~kept, 0])
 
+    @pytest.mark.parametrize("impl", ["jnp", "pallas"])
+    @pytest.mark.parametrize("nbins", [1, 3])
+    def test_wire_segments_are_lane_planar(self, rng, nbins, impl):
+        """The wire layout of DESIGN.md section 1.5, word by word: lane
+        ``k`` of item ``(f, o, d)`` of round ``r`` sits at word ``d*W +
+        w_f + k*C_f + o``, for flows of 1 to 5 words and 1 to 3 rounds;
+        ``place_rows`` with the flow's capacity as its stride writes the
+        same words; and ``rows_from_words`` reads every round's segments
+        back as the rows, in the dense owner layout (row ``d*R*C_f + r*C_f
+        + o``) the transport hands the exchange."""
+        from repro.core.object_container import rows_from_words
+        n, nflows = 240, 5
+        roww = np.arange(1, nflows + 1)
+        caps = rng.integers(1, 7, nflows)
+        rounds = rng.integers(1, 4, nflows)
+        bins = jnp.asarray(rng.integers(0, nbins, n), jnp.int32)
+        flow = jnp.asarray(rng.integers(0, nflows, n), jnp.int32)
+        valid = jnp.asarray(rng.random(n) < 0.8)
+        _, offs = ops.multi_bin_offsets(bins, flow, nbins, nflows, valid)
+        woff = np.concatenate([[0], np.cumsum(caps * roww)[:-1]])
+        wtot = int((caps * roww).sum())
+        total = nbins * wtot
+        wmax = nflows
+        rows = jnp.asarray(rng.integers(1, 1 << 31, (n, wmax)), jnp.uint32)
+        tables = [jnp.asarray(t, jnp.int32) for t in (woff, roww, caps,
+                                                      rounds)]
+        b_, f_, o_, v_ = (np.asarray(x) for x in (bins, flow, offs, valid))
+        rows_np = np.asarray(rows)
+        bufs = []
+        for r in range(int(rounds.max())):
+            buf = np.asarray(ops.pack_rows(
+                rows, bins, flow, offs, valid, r, tables[0], tables[1],
+                tables[2], tables[3], wtot, total, impl=impl))
+            expect = np.zeros(total, np.uint32)
+            for i in range(n):
+                f = f_[i]
+                o = o_[i] - r * caps[f]
+                if v_[i] and rounds[f] > r and 0 <= o < caps[f]:
+                    for k in range(roww[f]):
+                        expect[b_[i] * wtot + woff[f] + k * caps[f] + o] = \
+                            rows_np[i, k]
+            assert np.array_equal(buf, expect), r
+            slots = ops.ragged_slots(bins, flow, offs, valid, r, tables[0],
+                                     tables[1], tables[2], tables[3], wtot,
+                                     total, impl=impl)
+            placed = np.zeros(total, np.uint32)
+            for f in range(nflows):
+                mine = jnp.where(flow == f, slots, total)
+                placed |= np.asarray(ops.place_rows(
+                    jnp.zeros((total,), jnp.uint32), mine,
+                    rows[:, :roww[f]], int(caps[f]), impl=impl))
+            assert np.array_equal(placed, expect), r
+            bufs.append(buf.reshape(nbins, wtot))
+        for f in range(nflows):
+            c, rf = int(caps[f]), int(rounds[f])
+            seg = np.stack([bufs[r][:, woff[f]:woff[f] + c * roww[f]]
+                            for r in range(rf)], axis=1)
+            got = np.asarray(rows_from_words(jnp.asarray(seg), int(roww[f])))
+            expect = np.zeros((nbins * rf * c, roww[f]), np.uint32)
+            live = (f_ == f) & v_ & (o_ < rf * c)
+            expect[b_[live] * rf * c + o_[live]] = rows_np[live, :roww[f]]
+            assert np.array_equal(got, expect), f
+
 
 class TestFlashAttention:
     @pytest.mark.parametrize(

@@ -13,7 +13,9 @@ Two more programs, the 1-chip ISx step (push + drain) and the 1-chip
 speculative find, pin the library's layer scopes (``costs.scope``,
 DESIGN.md section 1.11) in the compiled HLO: every op the library
 traces carries a ``bcl.`` scope, and the program compiled with the
-scopes is the program compiled without them, op for op.
+scopes is the program compiled without them, op for op.  They also pin
+the lane-planar wire (DESIGN.md section 1.5): the transport reads its
+arrivals and replies back without a gather.
 
 The topology is described inside a fixture, never at import, so every
 pytest worker collects the same tests and only the worker that runs this
@@ -296,8 +298,8 @@ def _without_names(text):
 def test_library_ops_carry_a_layer_scope(name, compiled_hlo, on_tpu):
     """Every traced op the library emits (its ``op_name`` starts at the
     jitted program, ``jit(...)``) names a scope of the vocabulary; the
-    ISx gathers and scatters land in the transport and the queue, the
-    lookup's binning in the owner probe."""
+    ISx gathers land in the queue's drain and its scatters in the
+    transport and the queue, the lookup's binning in the owner probe."""
     text = compiled_hlo(name)
     seen, missing = {}, []
     for instr, opcode, op_name in _ops(text):
@@ -311,8 +313,7 @@ def test_library_ops_carry_a_layer_scope(name, compiled_hlo, on_tpu):
     assert not missing, "ops with no layer scope:\n" + "\n".join(missing)
     assert set().union(*seen.values()) <= SCOPES
     if name == "isx_step":
-        assert seen["gather"] == {"bcl.transport.request",
-                                  "bcl.queue.drain"}
+        assert seen["gather"] == {"bcl.queue.drain"}
         assert "bcl.queue.push" in seen["scatter"]
         assert "bcl.exchange.bin" in seen["custom-call"]
     else:
@@ -330,3 +331,15 @@ def test_scopes_change_no_op(name, compiled_hlo, on_tpu):
     plain = _without_names(compiled_hlo(name, scoped=False))
     assert len(scoped) > 100
     assert scoped == plain
+
+
+@pytest.mark.parametrize("name", sorted(SCOPE_PROGRAMS))
+def test_transport_reads_back_without_a_gather(name, compiled_hlo, on_tpu):
+    """The wire's flow segments are lane-planar, so the transport's
+    read-back of arrivals and replies (``rows_from_words``) is a reshape
+    of major dimensions: no gather of either program carries a
+    ``bcl.transport.`` scope."""
+    gathers = [(instr, op_name) for instr, opcode, op_name
+               in _ops(compiled_hlo(name)) if opcode == "gather"]
+    assert gathers
+    assert not [g for g in gathers if "bcl.transport." in (g[1] or "")]
